@@ -43,6 +43,8 @@ def main() -> None:
     ap.add_argument("--json-dir", default="",
                     help="write BENCH_<tag>.json per module into this dir")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     if args.json_dir:
         os.makedirs(args.json_dir, exist_ok=True)

@@ -2,7 +2,7 @@
 (a) native passthrough, (b) static PD co-location (head-of-line blocking),
 (c) FlexNPU dynamic PD co-location, (d) static PD disaggregation with the
 KV cache streamed across a 2-device session in layer-wise chunks —
-reproducing Table 1 and Table 4's mechanisms live on CPU.  The engine
+reproducing Table 1 and Table 4's mechanisms live.  The engine
 speaks only the session API (repro.core.connect); swapping modes swaps the
 session backend, never the engine code — that is the transparency
 property, and the outputs stay bit-identical across every mode.
@@ -12,7 +12,10 @@ Control-plane v3: ``--policy`` picks the dispatch policy by registry name
 transport chunking (0 = one blob per request).
 
     PYTHONPATH=src python examples/serve_dynamic_pd.py
-        [--policy dynamic_pd] [--kv-chunk-layers 4]
+        [--policy dynamic_pd] [--kv-chunk-layers 4] [--reduced]
+
+Without ``--reduced`` it serves olmo-1b at its published config, which
+needs an accelerator; ``--reduced`` serves the CPU-sized toy.
 """
 import argparse
 import sys
@@ -47,9 +50,13 @@ def main():
     ap.add_argument("--kv-chunk-layers", type=int, default=4,
                     help="disagg mode: stream the KV cache as this many "
                          "layer-group chunks (0 = one blob)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve olmo-1b's reduced-width toy (CPU runs)")
     args = ap.parse_args()
 
-    cfg = get_config("olmo-1b").reduced()
+    cfg = get_config("olmo-1b")
+    if args.reduced:
+        cfg = cfg.reduced()
     model = build_model(cfg)
     params = unbox(model.init(jax.random.PRNGKey(0)))
     print("burst of 6 requests, 2 decode slots (backlog scenario):\n")

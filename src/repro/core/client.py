@@ -207,14 +207,8 @@ class PassthroughClient(RuntimeAPI):
                 return
             fn, args, kwargs, fut = item
             try:
-                out = fn(*args, **kwargs)
-                try:
-                    import jax
-                    out = jax.block_until_ready(out)
-                except Exception:
-                    pass
-                err = None
-            except BaseException as e:
+                out, err = self.backend.run(fn, args, kwargs), None
+            except BaseException as e:  # propagate into the future
                 out, err = None, e
             # resolve the future BEFORE waking synchronize(): a caller that
             # synchronizes then inspects futures must see them done
@@ -234,7 +228,11 @@ class PassthroughClient(RuntimeAPI):
         return f
 
     def close(self):
+        """Stop the stream thread.  Joining it releases what its last op
+        referenced (device buffers included) before close returns."""
         self._q.put(None)
+        if threading.current_thread() is not self._thread:
+            self._thread.join(timeout=5)
 
     def _handle(self) -> int:
         with self._lock:

@@ -51,6 +51,7 @@ import time
 from collections import deque
 from typing import Any, Callable, Deque, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.api import (CONTROL_OPS, ENGINE_COMPUTE, Future,
@@ -72,21 +73,27 @@ from repro.sched.dispatch import FIFOPolicy
 
 
 class RealBackend:
-    """Executes launches in-process (CPU JAX here; TPU in production)."""
+    """Executes launches in-process on one JAX device (``device``; None
+    means JAX's default device)."""
+
+    def __init__(self, device=None):
+        self.device = device
 
     def now(self) -> float:
         return time.monotonic()
 
+    def run(self, fn: Callable, args=(), kwargs=None) -> Any:
+        """Call ``fn`` with this device as the default and wait for its
+        result like a device stream sync, so exec_time is honest.  An
+        asynchronous device error (an HBM OOM, say) is raised here."""
+        with jax.default_device(self.device):
+            out = fn(*args, **(kwargs or {}))
+        return jax.block_until_ready(out)
+
     def execute(self, op: OpDescriptor) -> Any:
         if op.fn is None:
             return None
-        out = op.fn(*op.args, **op.kwargs)
-        try:  # block like a device stream sync so exec_time is honest
-            import jax
-            out = jax.block_until_ready(out)
-        except Exception:
-            pass
-        return out
+        return self.run(op.fn, op.args, op.kwargs)
 
     def estimate(self, op: OpDescriptor) -> float:
         return float(op.meta.get("est_duration", 1e-4))

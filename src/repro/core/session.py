@@ -23,6 +23,9 @@ Modes:
                       simulator drives ``select_next``/``mark_complete``
                       against a virtual clock (caller supplies the backend).
 
+In both real modes virtual device ``i`` executes on ``jax.devices()[i]``
+(``Session.jax_device(i)``).
+
 Every device has its **own handle tables and memory accounting** — handles
 are only meaningful on the device that issued them, and clients carry an
 instance tag so co-located logical instances cannot free each other's
@@ -38,6 +41,8 @@ from __future__ import annotations
 
 import copy as _copy
 from typing import Callable, Dict, List, Optional, Union
+
+import jax
 
 from repro.core.api import ENGINE_COMPUTE, Future, MemcpyKind, Phase, RuntimeAPI
 from repro.core.client import FlexClient, PassthroughClient
@@ -58,9 +63,22 @@ def _policy_for(policy, device_id: int):
     return policy(device_id)                # factory: callable(device_id)
 
 
-def _backend_for(backend, device_id: int):
+def _chips(n: int) -> list:
+    """The JAX devices that virtual devices ``0..n-1`` execute on: virtual
+    device ``i`` is ``jax.devices()[i]``.  A session larger than the host
+    raises, except on the CPU, whose devices all share host memory: there
+    the virtual devices wrap around the host's CPU devices."""
+    chips = jax.devices()
+    if n > len(chips) and chips[0].platform != "cpu":
+        raise ValueError(
+            f"a session of {n} devices needs {n} {chips[0].platform} "
+            f"devices; this host has {len(chips)}")
+    return [chips[i % len(chips)] for i in range(n)]
+
+
+def _backend_for(backend, device_id: int, chips: list):
     if backend is None:
-        return RealBackend()
+        return RealBackend(chips[device_id])
     if callable(backend) and not hasattr(backend, "now"):
         return backend(device_id)           # factory: callable(device_id)
     return backend                          # shared (e.g. one sim clock)
@@ -114,6 +132,14 @@ class Session(RuntimeAPI):
 
     def daemon(self, device_id: int) -> Optional[FlexDaemon]:
         return self.daemons[device_id]
+
+    def jax_device(self, device_id: int):
+        """The JAX device that virtual device ``device_id`` executes on
+        (real-execution sessions only)."""
+        d = self.daemons[device_id]
+        backend = d.backend if d is not None \
+            else self._clients[device_id].backend
+        return backend.device
 
     # -- RuntimeAPI delegation to the current device ------------------------
     def malloc(self, nbytes: int, *, tag: str = "") -> int:
@@ -275,6 +301,8 @@ def connect(mode: str = "flex", devices: int = 1, *,
                          "(e.g. SimBackend over the event-loop clock)")
     clients: List[RuntimeAPI] = []
     daemons: List[Optional[FlexDaemon]] = []
+    chips = _chips(devices) \
+        if backend is None or mode == "passthrough" else []
     shared = SharedEventTable() if mode != "passthrough" else None
     sanitizer = None
     timeline = None
@@ -287,10 +315,10 @@ def connect(mode: str = "flex", devices: int = 1, *,
             timeline = Timeline()           # one recorder spans the session
     for i in range(devices):
         if mode == "passthrough":
-            clients.append(PassthroughClient())
+            clients.append(PassthroughClient(RealBackend(chips[i])))
             daemons.append(None)
             continue
-        d = FlexDaemon(i, _backend_for(backend, i),
+        d = FlexDaemon(i, _backend_for(backend, i, chips),
                        policy=_policy_for(policy, i), shared_events=shared,
                        queues=queues(i) if callable(queues) else queues,
                        sanitizer=sanitizer, timeline=timeline)

@@ -2,6 +2,8 @@
 logit softcap).
 
 Blocked online-softmax with BlockSpec VMEM tiling:
+  * the wrapper lays q/k/v out heads-major ([B, H, S, D]) so every block is
+    a [block, D] tile of one head — the TPU tiles the last two dims;
   * grid = (batch, q_heads, q_blocks, kv_blocks), kv innermost so fp32
     accumulators live in VMEM scratch across the kv sweep;
   * block_q x block_kv tiles sized for VMEM (defaults 512x512 ~= 1.5 MB of
@@ -17,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-
-from repro.kernels.tpu_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -46,9 +46,9 @@ def _kernel(q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)       # [bq, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # [bk, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)             # [bq, D]
+        k = k_ref[0, 0].astype(jnp.float32)             # [bk, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [bq, bk]
@@ -63,20 +63,20 @@ def _kernel(q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref, *,
             valid &= kpos > qpos - window
         s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_ref[...]                             # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
+        m_ref[...] = m_new
 
     @pl.when(ik == nkv - 1)
     def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
-        out_ref[0, :, 0, :] = out.astype(out_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        out_ref[0, 0] = out.astype(out_ref.dtype)
 
 
 def flash_attention_kernel(q, k, v, *, causal: bool = True, scale: float,
@@ -94,12 +94,11 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, scale: float,
     block_kv = min(block_kv, T)
     pad_q = (-S) % block_q
     pad_kv = (-T) % block_kv
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-    if pad_kv:
-        k = jnp.pad(k, ((0, 0), (0, pad_kv), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_kv), (0, 0), (0, 0)))
-    Sp, Tp = q.shape[1], k.shape[1]
+    # heads-major: [B, H, S, D]
+    q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    k = jnp.pad(k, ((0, 0), (0, pad_kv), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    v = jnp.pad(v, ((0, 0), (0, pad_kv), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    Sp, Tp = q.shape[2], k.shape[2]
     nq, nkv = Sp // block_q, Tp // block_kv
 
     kernel = functools.partial(
@@ -110,24 +109,24 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, scale: float,
         kernel,
         grid=(B, H, nq, nkv),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, D),
-                         lambda b, h, iq, ik, g=G: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, block_kv, 1, D),
-                         lambda b, h, iq, ik, g=G: (b, ik, h // g, 0)),
+            pl.BlockSpec((1, 1, block_q, D),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_kv, D),
+                         lambda b, h, iq, ik, g=G: (b, h // g, ik, 0)),
+            pl.BlockSpec((1, 1, block_kv, D),
+                         lambda b, h, iq, ik, g=G: (b, h // g, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, block_q, D),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        out_shape=jax.ShapeDtypeStruct((B, Sp, H, D), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, H, Sp, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(q, k, v)
-    return out[:, :S]
+    return out.transpose(0, 2, 1, 3)[:, :S]

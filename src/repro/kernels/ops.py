@@ -1,13 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret=None`` auto-selects: real Mosaic lowering on TPU, interpret mode
-(Python execution of the kernel body) on CPU — which is how this container
-validates the kernels against the ref.py oracles.
+The kernels lower through Mosaic for the TPU.  ``interpret=True`` runs the
+kernel body in Python instead, which is how the CPU tests check the kernels
+against the ref.py oracles; without it, a non-TPU backend refuses the call.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 
@@ -16,19 +15,13 @@ from repro.kernels.paged_attention import paged_attention_kernel
 from repro.kernels.ssd_scan import ssd_scan_kernel
 
 
-def _auto_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("scale", "softcap", "interpret"))
 def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
                     scale: float, softcap: float = 0.0,
-                    interpret: Optional[bool] = None):
+                    interpret: bool = False):
     return paged_attention_kernel(
         q, k_pages, v_pages, page_tables, lengths, scale=scale,
-        softcap=softcap, interpret=_auto_interpret(interpret))
+        softcap=softcap, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -37,16 +30,14 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
 def flash_attention(q, k, v, *, causal: bool = True, scale: float,
                     window: int = 0, softcap: float = 0.0,
                     block_q: int = 512, block_kv: int = 512,
-                    interpret: Optional[bool] = None):
+                    interpret: bool = False):
     return flash_attention_kernel(
         q, k, v, causal=causal, scale=scale, window=window, softcap=softcap,
-        block_q=block_q, block_kv=block_kv,
-        interpret=_auto_interpret(interpret))
+        block_q=block_q, block_kv=block_kv, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 256, initial_state=None,
-             interpret: Optional[bool] = None):
+             interpret: bool = False):
     return ssd_scan_kernel(x, dt, A, Bm, Cm, chunk=chunk,
-                           initial_state=initial_state,
-                           interpret=_auto_interpret(interpret))
+                           initial_state=initial_state, interpret=interpret)

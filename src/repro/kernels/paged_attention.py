@@ -4,6 +4,9 @@ The decode phase — the memory-bandwidth-bound side of the paper's PD
 imbalance — is dominated by streaming the KV cache.  TPU-native design:
 
   * grid = (batch, kv_heads, pages): one program instance per KV page;
+  * the wrapper lays the page pools out heads-major ([KVH, P, ps, D]) so a
+    page block is a [ps, D] tile of one KV head — the TPU tiles the last
+    two dims;
   * the **page table is scalar-prefetched** (PrefetchScalarGridSpec) so the
     BlockSpec index_map can translate logical page -> physical page while the
     previous page's compute is in flight (HBM->VMEM pipelining by Mosaic);
@@ -20,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-
-from repro.kernels.tpu_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -46,8 +47,8 @@ def _kernel(page_tables_ref, lengths_ref,        # scalar prefetch
     @pl.when(page_start < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)              # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # [ps, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)              # [ps, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [G, ps]
@@ -56,21 +57,19 @@ def _kernel(page_tables_ref, lengths_ref,        # scalar prefetch
         pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos < length, s, NEG_INF)
 
-        m_prev = m_ref[:, 0]                             # [G]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_ref[...]                              # [G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new[:, None])               # [G, ps]
-        l_new = l_ref[:, 0] * alpha + jnp.sum(pexp, axis=1)
-        acc = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        pexp = jnp.exp(s - m_new)                        # [G, ps]
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             pexp, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # [G, D]
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
-        acc_ref[...] = acc
+        m_ref[...] = m_new
 
     @pl.when(p == pages - 1)
     def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         out_ref[0, 0] = out.astype(out_ref.dtype)
 
 
@@ -84,6 +83,8 @@ def paged_attention_kernel(q, k_pages, v_pages, page_tables, lengths, *,
     maxp = page_tables.shape[1]
     G = H // KVH
     qr = q.reshape(B, KVH, G, D)
+    k_pages = k_pages.transpose(2, 0, 1, 3)              # [KVH, P, ps, D]
+    v_pages = v_pages.transpose(2, 0, 1, 3)
 
     grid = (B, KVH, maxp)
     kernel = functools.partial(_kernel, page_size=ps, pages=maxp,
@@ -96,10 +97,10 @@ def paged_attention_kernel(q, k_pages, v_pages, page_tables, lengths, *,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G, D), lambda b, h, p, pt, ln: (b, h, 0, 0)),
-                pl.BlockSpec((1, ps, 1, D),
-                             lambda b, h, p, pt, ln: (pt[b, p], 0, h, 0)),
-                pl.BlockSpec((1, ps, 1, D),
-                             lambda b, h, p, pt, ln: (pt[b, p], 0, h, 0)),
+                pl.BlockSpec((1, 1, ps, D),
+                             lambda b, h, p, pt, ln: (h, pt[b, p], 0, 0)),
+                pl.BlockSpec((1, 1, ps, D),
+                             lambda b, h, p, pt, ln: (h, pt[b, p], 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, D),
                                    lambda b, h, p, pt, ln: (b, h, 0, 0)),
@@ -110,7 +111,7 @@ def paged_attention_kernel(q, k_pages, v_pages, page_tables, lengths, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_tables, lengths, qr, k_pages, v_pages)

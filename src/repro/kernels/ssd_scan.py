@@ -8,7 +8,9 @@ a GPU warp-level scan, each chunk becomes dense MXU work —
     never leaves the core.
 
 grid = (batch, heads, chunks); per-program blocks are one (sequence-chunk x
-head) tile: x [Q, P], dt [Q], B/C [Q, N].
+head) tile: x [Q, P], dt [1, Q], B/C [Q, N].  The wrapper lays the inputs out
+heads-major ([B, H, S, P]) so each block is a tile of the last two dims, as
+the TPU requires; A is read from SMEM.
 """
 from __future__ import annotations
 
@@ -18,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-
-from repro.kernels.tpu_compat import CompilerParams
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
@@ -31,25 +31,33 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
     def _init():
         state_ref[...] = init_ref[0, 0].astype(jnp.float32)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # [Q, P]
-    dt = dt_ref[0, :, 0].astype(jnp.float32)         # [Q]
-    A = a_ref[0].astype(jnp.float32)                 # scalar (this head)
-    Bm = b_ref[0, :, 0, :].astype(jnp.float32)       # [Q, N]
-    Cm = c_ref[0, :, 0, :].astype(jnp.float32)       # [Q, N]
+    x = x_ref[0, 0].astype(jnp.float32)              # [Q, P]
+    dt = dt_ref[0, 0].astype(jnp.float32)            # [1, Q]
+    A = a_ref[pl.program_id(1)].astype(jnp.float32)  # scalar (this head)
+    Bm = b_ref[0, 0].astype(jnp.float32)             # [Q, N]
+    Cm = c_ref[0, 0].astype(jnp.float32)             # [Q, N]
 
     # padded tail positions contribute nothing (dt = 0 -> decay 1, dBx 0)
-    pos = c_idx * chunk + jax.lax.iota(jnp.int32, chunk)
+    pos = c_idx * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
     dt = jnp.where(pos < seq_len, dt, 0.0)
 
-    dA = dt * A                                      # [Q] log-decay steps
-    cum = jnp.cumsum(dA)                             # [Q]
-    # L[i,j] = exp(sum_{k in (j, i]} dA_k) for i >= j
-    seg = cum[:, None] - cum[None, :]
     row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(row >= col, jnp.exp(seg), 0.0)     # [Q, Q]
+    dA = dt * A                                      # [1, Q] log-decay steps
+    # inclusive prefix sum as a matmul: cum[j] = sum_{k <= j} dA[k]
+    cum = jax.lax.dot_general(
+        dA, jnp.where(row <= col, 1.0, 0.0), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)          # [1, Q]
+    total = jnp.sum(dA, axis=1, keepdims=True)       # [1, 1] = cum[-1]
+    # row-vector -> column-vector through the diagonal (no transpose)
+    diag = row == col
+    cum_c = jnp.sum(jnp.where(diag, cum, 0.0), axis=1, keepdims=True)
+    dt_c = jnp.sum(jnp.where(diag, dt, 0.0), axis=1, keepdims=True)
+    # L[i,j] = exp(sum_{k in (j, i]} dA_k) for i >= j
+    L = jnp.where(row >= col, jnp.exp(cum_c - cum), 0.0)  # [Q, Q]
 
-    xq = x * dt[:, None]                             # dt folded into x
+    xq = x * dt_c                                    # dt folded into x
     scores = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * L      # [Q, Q]
@@ -61,14 +69,13 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
     state = state_ref[...]                           # [P, N]
     y_off = jax.lax.dot_general(Cm, state, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    y = y + y_off * jnp.exp(cum)[:, None]
+    y = y + y_off * jnp.exp(cum_c)
 
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update: S' = exp(cum[-1]) S + sum_t exp(cum[-1]-cum[t]) xq_t B_t^T
-    decay_out = jnp.exp(cum[-1] - cum)               # [Q]
-    xw = xq * decay_out[:, None]                     # [Q, P]
-    state_new = jnp.exp(cum[-1]) * state + jax.lax.dot_general(
+    xw = xq * jnp.exp(total - cum_c)                 # [Q, P]
+    state_new = jnp.exp(total) * state + jax.lax.dot_general(
         xw, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # [P, N]
     state_ref[...] = state_new
@@ -87,12 +94,12 @@ def ssd_scan_kernel(x, dt, A, Bm, Cm, *, chunk: int = 256,
     rep = H // G
     chunk = min(chunk, max(S, 8))
     pad = (-S) % chunk
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    Sp = x.shape[1]
+    # heads-major: x [B, H, S, P], dt [B, H, 1, S], B/C [B, G, S, N]
+    x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0))).transpose(0, 2, 1)[:, :, None]
+    Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    Sp = x.shape[2]
     nchunks = Sp // chunk
     if initial_state is None:
         initial_state = jnp.zeros((B, H, P, N), jnp.float32)
@@ -103,26 +110,26 @@ def ssd_scan_kernel(x, dt, A, Bm, Cm, *, chunk: int = 256,
         kernel,
         grid=(B, H, nchunks),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
-            pl.BlockSpec((1, chunk, 1, N),
-                         lambda b, h, c, r=rep: (b, c, h // r, 0)),
-            pl.BlockSpec((1, chunk, 1, N),
-                         lambda b, h, c, r=rep: (b, c, h // r, 0)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, chunk, N),
+                         lambda b, h, c, r=rep: (b, h // r, c, 0)),
+            pl.BlockSpec((1, 1, chunk, N),
+                         lambda b, h, c, r=rep: (b, h // r, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sp, H, P), x.dtype),
+            jax.ShapeDtypeStruct((B, H, Sp, P), x.dtype),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, Bm, Cm, initial_state)
-    return y[:, :S], final
+    return y.transpose(0, 2, 1, 3)[:, :S], final

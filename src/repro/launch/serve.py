@@ -1,7 +1,8 @@
 """Serving launcher — both execution paths:
 
-  * real:  RealEngine on this process's devices (reduced configs on CPU),
-           under any FlexNPU policy:
+  * real:  RealEngine on this process's devices at the architecture's
+           published config (``--reduced`` cuts it to a CPU-sized toy),
+           under any FlexNPU policy; exits non-zero if a request failed:
            python -m repro.launch.serve --arch olmo-1b --mode dynamic_pd \
                --requests 16 --rate 4
   * sim:   384-card cluster simulation with the paper's deployments:
@@ -16,6 +17,7 @@ per-device handle/memory accounting after the run.
 from __future__ import annotations
 
 import argparse
+import sys
 
 import jax
 import numpy as np
@@ -24,14 +26,17 @@ import numpy as np
 def run_real(arch: str, mode: str, n_requests: int, rate: float,
              prompt_len: int = 16, max_new: int = 16,
              max_num_seqs: int = 4, seed: int = 0, verbose: bool = True,
-             show_session: bool = False, policy: str = ""):
+             show_session: bool = False, policy: str = "",
+             reduced: bool = False):
     from repro.distributed.sharding import unbox
     from repro.configs import get_config
     from repro.models import build_model
     from repro.serving.engine import RealEngine
     from repro.serving.request import Request
 
-    cfg = get_config(arch).reduced()
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced()
     model = build_model(cfg)
     params = unbox(model.init(jax.random.PRNGKey(seed)))
     rng = np.random.default_rng(seed)
@@ -129,6 +134,9 @@ def main():
     ap.add_argument("--rate", type=float, default=4.0)
     ap.add_argument("--show-session", action="store_true",
                     help="print per-device session handle/memory stats")
+    ap.add_argument("--reduced", action="store_true",
+                    help="real path: serve the config's reduced-width toy "
+                         "(CPU runs) instead of its published config")
     args = ap.parse_args()
     if args.sim:
         run_sim(args.arch, args.deployment, args.workload,
@@ -136,8 +144,14 @@ def main():
                 cluster_policy=args.cluster_policy,
                 dispatch_policy=args.dispatch_policy, drive=args.drive)
     else:
-        run_real(args.arch, args.mode, args.requests, args.rate,
-                 show_session=args.show_session, policy=args.policy)
+        from repro.launch.compile_cache import use_compile_cache
+        use_compile_cache()
+        res = run_real(args.arch, args.mode, args.requests, args.rate,
+                       show_session=args.show_session, policy=args.policy,
+                       reduced=args.reduced)
+        if res["failed"]:
+            sys.exit(f"{res['failed']} of {res['generated']} requests "
+                     f"failed")
 
 
 if __name__ == "__main__":

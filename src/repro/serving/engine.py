@@ -1,4 +1,4 @@
-"""Real-execution serving engine (CPU JAX here, TPU in production).
+"""Real-execution serving engine (JAX on the TPU, or on the CPU in tests).
 
 Continuous batching over slot-structured dense KV caches.  ALL device work is
 issued through the session-based v2 ``RuntimeAPI`` verbs: the engine opens a
@@ -28,6 +28,9 @@ Requests are routed to replicas by a :class:`~repro.sched.ClusterPolicy`
 from the v3 registry (``cluster_policy="least_loaded"`` by default), so
 the same routing layer fronts the real engine and the cluster simulator.
 ``replicas=1`` (the default) is the v3 single-device engine, byte-for-byte.
+Each replica's weights, slot cache and step inputs live on the JAX device
+its session device executes on (``Session.jax_device``); under disagg the
+unpacked KV lands on the decode device.
 
 Execution queues (v4): each device exposes ``compute_queues`` compute
 queues (plus a copy queue).  With more than one, decode is PINNED to the
@@ -45,13 +48,14 @@ the stream heads (stream-ordered dispatch, daemon v2).
 """
 from __future__ import annotations
 
+import functools
+import logging
 import math
 import threading
 import time
 from typing import Dict, List, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.api import Phase
@@ -66,6 +70,27 @@ from repro.sched import (AdmissionPolicy, AdmissionView, ClusterPolicy,
 from repro.models.model import Model
 from repro.serving.request import Request, RequestState, summarize
 
+_log = logging.getLogger(__name__)
+
+
+# The jitted steps are shared by every engine of the process (the model is
+# a static argument): engines and replicas of one model compile each shape
+# once per device.
+@functools.partial(jax.jit, static_argnums=0)
+def _prefill_step(model: Model, params, toks, cache):
+    return model.prefill(params, {"tokens": toks}, cache)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _decode_step(model: Model, params, toks, cache, lens):
+    return model.decode(params, toks, cache, lens)
+
+
+def _empty_cache(model: Model, batch: int, max_len: int, device):
+    """A zeroed KV cache created directly on ``device``."""
+    with jax.default_device(device):
+        return model.init_cache(batch, max_len)
+
 
 def _pack_cache(cache):
     """Flatten a KV-cache pytree into one contiguous byte blob (+ recipe)."""
@@ -78,13 +103,15 @@ def _pack_cache(cache):
     return blob, treedef, spec
 
 
-def _unpack_cache(blob, treedef, spec):
+def _unpack_cache(blob, treedef, spec, device):
+    """Rebuild a packed KV-cache pytree on ``device``."""
     buf = bytes(blob) if not isinstance(blob, (bytes, bytearray)) else blob
     leaves, off = [], 0
     for shape, dtype in spec:
         n = int(np.prod(shape, dtype=np.int64)) * np.dtype(dtype).itemsize
-        leaves.append(jnp.asarray(
-            np.frombuffer(buf[off:off + n], dtype=dtype).reshape(shape)))
+        leaves.append(jax.device_put(
+            np.frombuffer(buf[off:off + n], dtype=dtype).reshape(shape),
+            device))
         off += n
     return jax.tree.unflatten(treedef, leaves)
 
@@ -105,15 +132,23 @@ class _Replica:
     (``failed`` / ``ewma_step`` / ``load()``), so cluster policies route
     real-engine replicas exactly like simulator instances."""
 
-    def __init__(self, engine: "RealEngine", index: int,
-                 client, daemon, client_d, daemon_d):
+    def __init__(self, engine: "RealEngine", index: int, p_dev: int,
+                 d_dev: int):
         self.engine = engine
         self.index = index
         self.name = f"replica{index}"
-        self.client = client          # prefill-side client
-        self.daemon = daemon
-        self.client_d = client_d      # decode-side client (disagg: peer dev)
-        self.daemon_d = daemon_d
+        sess = engine.session
+        self.client = sess.device(p_dev)      # prefill-side client
+        self.daemon = sess.daemon(p_dev)
+        self.client_d = sess.device(d_dev)    # decode-side (disagg: peer)
+        self.daemon_d = sess.daemon(d_dev)
+        client, client_d = self.client, self.client_d
+        # the JAX devices each side executes on, and the weights there
+        self.chip_p = sess.jax_device(p_dev)
+        self.chip_d = sess.jax_device(d_dev)
+        self.params_p = jax.device_put(engine.params, self.chip_p)
+        self.params_d = self.params_p if self.chip_d == self.chip_p \
+            else jax.device_put(engine.params, self.chip_d)
         cq = engine.compute_queues
         if cq > 1:
             # decode owns the last compute queue outright; prefill streams
@@ -129,8 +164,8 @@ class _Replica:
         self.stream_p = self.streams_p[0]
         self._rr = 0
         # device state
-        self.slot_cache = engine.model.init_cache(engine.max_num_seqs,
-                                                  engine.max_len)
+        self.slot_cache = _empty_cache(engine.model, engine.max_num_seqs,
+                                       engine.max_len, self.chip_d)
         self.lengths = np.zeros((engine.max_num_seqs,), np.int32)
         self.slot_req: List[Optional[Request]] = [None] * engine.max_num_seqs
         self.next_tokens = np.zeros((engine.max_num_seqs,), np.int32)
@@ -235,22 +270,13 @@ class RealEngine:
                 p_dev, d_dev = 2 * r, 2 * r + 1
             else:
                 p_dev = d_dev = r
-            self.replicas.append(_Replica(
-                self, r, self.session.device(p_dev),
-                self.session.daemon(p_dev), self.session.device(d_dev),
-                self.session.daemon(d_dev)))
+            self.replicas.append(_Replica(self, r, p_dev, d_dev))
         # single-replica conveniences (the v3 attribute names)
         self.client = self.replicas[0].client
         self.daemon = self.replicas[0].daemon
         self.client_d = self.replicas[0].client_d
         self.stream_p = self.replicas[0].stream_p
         self.stream_d = self.replicas[0].stream_d
-
-        # jitted steps (shared: replicas run the same program)
-        self._prefill_jit = jax.jit(
-            lambda p, toks, cache: model.prefill(p, {"tokens": toks}, cache))
-        self._decode_jit = jax.jit(
-            lambda p, toks, cache, lens: model.decode(p, toks, cache, lens))
 
         # engine-level queues
         self.waiting_admission: List[Request] = []  # guarded-by: _lock
@@ -364,12 +390,13 @@ class RealEngine:
     def _launch_prefill(self, rep: _Replica, req: Request) -> None:  # holds: _lock
         req.state = RequestState.PREFILLING
         req.instance = rep.name
-        toks = jnp.asarray(np.asarray(req.prompt_tokens, np.int32))[None, :]
-        cache = self.model.init_cache(1, self.max_len)
+        toks = jax.device_put(
+            np.asarray(req.prompt_tokens, np.int32)[None, :], rep.chip_p)
+        cache = _empty_cache(self.model, 1, self.max_len, rep.chip_p)
         t0 = time.monotonic()
         fut = rep.client.launch(
-            rep.next_prefill_stream(), self._prefill_jit, self.params, toks,
-            cache, phase=Phase.PREFILL,
+            rep.next_prefill_stream(), _prefill_step, self.model,
+            rep.params_p, toks, cache, phase=Phase.PREFILL,
             meta={"tokens": req.prompt_len, "req_id": req.req_id})
         fut.add_done_callback(
             lambda f, r=req, rp=rep, t=t0: self._prefill_done(rp, r, f, t))
@@ -379,6 +406,7 @@ class RealEngine:
         try:
             logits, single_cache, lens = fut.result()
         except Exception:
+            _log.exception("prefill of request %d failed", req.req_id)
             with self._lock:
                 rep.prefilling_count = max(0, rep.prefilling_count - 1)
                 self._fail_locked(req)
@@ -459,8 +487,9 @@ class RealEngine:
         try:
             parts = [np.asarray(f.result(), dtype=np.uint8) for f in futs]
             blob = parts[0] if len(parts) == 1 else np.concatenate(parts)
-            cache = _unpack_cache(blob, treedef, spec)
+            cache = _unpack_cache(blob, treedef, spec, rep.chip_d)
         except Exception:
+            _log.exception("KV transfer of request %d failed", req.req_id)
             with self._lock:
                 self._fail_locked(req)
             return
@@ -501,11 +530,11 @@ class RealEngine:
         if rep.decode_inflight or rep.active_count == 0:
             return
         rep.decode_inflight = True
-        toks = jnp.asarray(rep.next_tokens)
-        lens = jnp.asarray(rep.lengths)
+        toks = jax.device_put(rep.next_tokens.copy(), rep.chip_d)
+        lens = jax.device_put(rep.lengths.copy(), rep.chip_d)
         t0 = time.monotonic()
         fut = rep.client_d.launch(
-            rep.stream_d, self._decode_jit, self.params, toks,
+            rep.stream_d, _decode_step, self.model, rep.params_d, toks,
             rep.slot_cache, lens, phase=Phase.DECODE,
             meta={"tokens": rep.active_count})
         fut.add_done_callback(
@@ -515,8 +544,19 @@ class RealEngine:
         try:
             logits, new_cache = fut.result()
         except Exception:
+            _log.exception("decode step failed on %s", rep.name)
             with self._lock:
+                # the step's requests end FAILED; requests still waiting
+                # for a slot go on to the next step
                 rep.decode_inflight = False
+                for slot, req in enumerate(rep.slot_req):
+                    if req is not None:
+                        rep.slot_req[slot] = None
+                        rep.lengths[slot] = 0
+                        rep.active_count -= 1
+                        self._fail_locked(req)
+                self._fill_slots_locked(rep)
+                self._ensure_decode_locked(rep)
             return
         now = time.monotonic()
         toks = np.argmax(np.asarray(logits), axis=-1)

@@ -1,8 +1,13 @@
 import os
 import sys
 
-# Tests must see ONE device (the dry-run sets 512 in its own process only).
+# Tests run on the CPU with four host devices, so that multi-device sessions
+# (replicas, disaggregated pairs) bind their virtual devices to distinct
+# JAX devices as they do on a four-chip host.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                               "--xla_force_host_platform_device_count=4")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 

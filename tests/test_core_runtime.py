@@ -7,7 +7,7 @@ import pytest
 from repro.core import (DynamicPDConfig, DynamicPDPolicy, FIFOPolicy,
                         FlexClient, FlexDaemon, OpDescriptor, OpType,
                         PassthroughClient, Phase, Profiler, RealBackend,
-                        StaticTimeSlicePolicy)
+                        StaticTimeSlicePolicy, connect)
 
 
 def make_daemon(policy=None):
@@ -67,6 +67,24 @@ def test_failed_device_errors_futures():
     fut = c.launch(0, lambda: 1, phase=Phase.DECODE)
     with pytest.raises(RuntimeError):
         fut.result(1.0)
+
+
+class _FailsOnSync:
+    """A launch result whose device execution failed asynchronously: like
+    a TPU array after an HBM OOM, it raises only when it is waited for."""
+
+    def block_until_ready(self):
+        raise RuntimeError("asynchronous device fault")
+
+
+@pytest.mark.parametrize("mode", ["flex", "passthrough"])
+def test_error_raised_after_dispatch_fails_the_future(mode):
+    with connect(mode=mode) as sess:
+        s = sess.create_stream(phase=Phase.DECODE)
+        fut = sess.launch(s, _FailsOnSync, phase=Phase.DECODE)
+        with pytest.raises(RuntimeError, match="asynchronous device fault"):
+            fut.result(5.0)
+        sess.destroy_stream(s)
 
 
 def test_profiler_phase_stats():

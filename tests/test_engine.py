@@ -7,8 +7,9 @@ import pytest
 from repro.configs import get_config
 from repro.distributed.sharding import unbox
 from repro.models import build_model
+from repro.serving import engine as E
 from repro.serving.engine import RealEngine
-from repro.serving.request import Request
+from repro.serving.request import Request, RequestState
 
 
 @pytest.fixture(scope="module")
@@ -92,3 +93,71 @@ def test_dynamic_pd_improves_ttft_under_backlog(setup):
     st_tp = results["static_colocate"][0]["output_tokens_per_s"]
     dy_tp = results["dynamic_pd"][0]["output_tokens_per_s"]
     assert dy_tp > 0.6 / slack * st_tp, (dy_tp, st_tp, slack)
+
+
+@pytest.mark.parametrize("mode", ["passthrough", "dynamic_pd"])
+def test_failing_decode_step_fails_its_requests(setup, monkeypatch, mode):
+    """A decode step that errors ends its requests FAILED with finish_time
+    stamped, and run() returns instead of waiting for its timeout."""
+    def broken(*args):
+        raise RuntimeError("decode fault")
+
+    monkeypatch.setattr(E, "_decode_step", broken)
+    cfg, model, params = setup
+    reqs = mk_requests(cfg, n=3, gap=0.0)
+    eng = RealEngine(model, params, mode=mode, max_num_seqs=2, max_len=64)
+    try:
+        res = eng.run(reqs, timeout=60)
+    finally:
+        eng.shutdown()
+    assert res["failed"] == len(reqs)
+    assert all(r.state == RequestState.FAILED and r.finish_time > 0
+               for r in reqs)
+
+
+def _devices_of(tree):
+    return set().union(*(x.devices() for x in jax.tree.leaves(tree)))
+
+
+@pytest.mark.parametrize("mode,replicas", [("dynamic_pd", 4), ("disagg", 2)])
+def test_replicas_bind_to_distinct_devices(setup, monkeypatch, mode,
+                                           replicas):
+    """On four devices, replicas=4 keeps each replica's weights and slot
+    cache on its own device, and disagg lands the prefilled KV on the decode
+    device; the tokens equal one-device passthrough's."""
+    cfg, model, params = setup
+    assert len(jax.devices()) >= 4      # conftest gives the CPU four
+    landed = []
+    unpack = E._unpack_cache
+
+    def spy(blob, treedef, spec, device):
+        cache = unpack(blob, treedef, spec, device)
+        landed.append((device, _devices_of(cache)))
+        return cache
+
+    monkeypatch.setattr(E, "_unpack_cache", spy)
+    outputs, placement = {}, None
+    for m, r in (("passthrough", 1), (mode, replicas)):
+        reqs = mk_requests(cfg, n=8, gap=0.0)
+        eng = RealEngine(model, params, mode=m, replicas=r, max_num_seqs=2,
+                         max_len=64)
+        try:
+            res = eng.run(reqs, timeout=300)
+            placement = [(rep.chip_p, rep.chip_d, _devices_of(rep.params_p),
+                          _devices_of(rep.params_d),
+                          _devices_of(rep.slot_cache))
+                         for rep in eng.replicas]
+        finally:
+            eng.shutdown()
+        assert res["completed"] == len(reqs)
+        outputs[m] = [q.output_tokens for q in reqs]
+    assert outputs[mode] == outputs["passthrough"]
+    assert len({c for p, d, *_ in placement for c in (p, d)}) == 4
+    for chip_p, chip_d, params_p, params_d, slot_cache in placement:
+        assert params_p == {chip_p}
+        assert params_d == slot_cache == {chip_d}
+    if mode == "disagg":
+        decode_chips = {d for _, d, *_ in placement}
+        assert len(landed) == 8
+        assert all(dev in decode_chips and devs == {dev}
+                   for dev, devs in landed)

@@ -135,6 +135,25 @@ def test_ssd_scan_initial_state():
                                rtol=1e-3, atol=1e-3)
 
 
+@pytest.mark.parametrize("kernel", ["flash", "paged", "ssd"])
+def test_kernels_refuse_the_cpu_without_interpret(kernel):
+    """Interpret mode runs only when asked for: off the TPU a kernel call
+    without interpret=True raises instead of falling back."""
+    z = jnp.zeros
+    with pytest.raises(ValueError, match="interpret"):
+        if kernel == "flash":
+            q = z((1, 16, 2, 32))
+            flash_attention(q, q, q, scale=0.2)
+        elif kernel == "paged":
+            pages = z((2, 8, 1, 32))
+            paged_attention(z((1, 2, 32)), pages, pages,
+                            jnp.zeros((1, 2), jnp.int32),
+                            jnp.ones((1,), jnp.int32), scale=0.2)
+        else:
+            ssd_scan(z((1, 16, 2, 16)), z((1, 16, 2)), -jnp.ones((2,)),
+                     z((1, 16, 1, 16)), z((1, 16, 1, 16)), chunk=8)
+
+
 def test_kernels_match_model_layers(rng_key):
     """Cross-check: the Pallas flash kernel agrees with the model's XLA
     blocked_attention (same math, different engines)."""

@@ -33,6 +33,39 @@ def test_connect_modes_and_device_count():
         connect(mode="sim")  # stepped mode requires a clock-bearing backend
 
 
+@pytest.mark.parametrize("mode", ["flex", "passthrough"])
+def test_virtual_devices_execute_on_jax_devices(mode):
+    """Virtual device i runs on jax.devices()[i]; the CPU's devices all
+    share host memory, so a larger session wraps around them."""
+    import jax
+    import jax.numpy as jnp
+    chips = jax.devices()
+    n = len(chips) + 2
+    with connect(mode=mode, devices=n) as sess:
+        assert [sess.jax_device(i) for i in range(n)] == \
+            [chips[i % len(chips)] for i in range(n)]
+        dev = min(2, len(chips) - 1)
+        sess.set_device(dev)
+        s = sess.create_stream()
+        out = sess.launch(s, lambda: jnp.zeros(3)).result(10.0)
+        assert out.devices() == {chips[dev]}
+        sess.destroy_stream(s)
+
+
+def test_session_larger_than_the_accelerator_host_raises(monkeypatch):
+    import jax
+
+    class Chip:
+        platform = "tpu"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [Chip()])
+    for mode in ("flex", "passthrough"):
+        with pytest.raises(ValueError, match="needs 2 tpu devices"):
+            connect(mode=mode, devices=2)
+        with connect(mode=mode, devices=1) as sess:
+            assert isinstance(sess.jax_device(0), Chip)
+
+
 def test_multi_device_routing_and_isolation():
     """Each device has its own daemon, handle tables, and accounting."""
     with connect(mode="flex", devices=2) as sess:
