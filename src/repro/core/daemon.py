@@ -60,7 +60,7 @@ from repro.core.api import (CONTROL_OPS, ENGINE_COMPUTE, Future,
 from repro.core.handles import HandleTable, SharedEventTable
 from repro.core.queues import (QueueId, parse_queue_spec, queue_key,
                                validate_queue_binding)
-from repro.core.profiler import Profiler
+from repro.core.profiler import Profiler, op_ids, span
 # import from the submodules, not the repro.sched package: the daemon loads
 # while repro.sched's own __init__ may still be executing (sched.cluster ->
 # repro.core.api -> this module), and submodule imports break that cycle
@@ -74,26 +74,36 @@ from repro.sched.dispatch import FIFOPolicy
 
 class RealBackend:
     """Executes launches in-process on one JAX device (``device``; None
-    means JAX's default device)."""
+    means JAX's default device).  With a ``timeline``, each launch records
+    the host spans ``flex.exec.call`` (the call returning: host-side
+    dispatch) and ``flex.exec.wait`` (``block_until_ready``) on the row of
+    session device ``device_id``."""
 
-    def __init__(self, device=None):
+    def __init__(self, device=None, timeline=None, device_id: int = 0):
         self.device = device
+        self.timeline = timeline
+        self.device_id = device_id
 
     def now(self) -> float:
         return time.monotonic()
 
-    def run(self, fn: Callable, args=(), kwargs=None) -> Any:
+    def run(self, fn: Callable, args=(), kwargs=None,
+            op: Optional[OpDescriptor] = None) -> Any:
         """Call ``fn`` with this device as the default and wait for its
         result like a device stream sync, so exec_time is honest.  An
-        asynchronous device error (an HBM OOM, say) is raised here."""
+        asynchronous device error (an HBM OOM, say) is raised here.
+        ``op``, where given, names the spans."""
+        tl = self.timeline
         with jax.default_device(self.device):
-            out = fn(*args, **(kwargs or {}))
-        return jax.block_until_ready(out)
+            with span(tl, "flex.exec.call", op=op, device=self.device_id):
+                out = fn(*args, **(kwargs or {}))
+        with span(tl, "flex.exec.wait", op=op, device=self.device_id):
+            return jax.block_until_ready(out)
 
     def execute(self, op: OpDescriptor) -> Any:
         if op.fn is None:
             return None
-        return self.run(op.fn, op.args, op.kwargs)
+        return self.run(op.fn, op.args, op.kwargs, op)
 
     def estimate(self, op: OpDescriptor) -> float:
         return float(op.meta.get("est_duration", 1e-4))
@@ -155,6 +165,11 @@ class FlexDaemon:
         # opt-in per-op Chrome-trace recorder (FLEX_PROFILE=1; one per
         # session, see repro.core.profiler.Timeline) — None means off
         self.timeline = timeline
+        # where host spans go: the timeline once the threaded drive over
+        # the real backend starts (start()); the stepped drive runs on
+        # virtual time, and its trace holds the ops alone
+        self._spans = None
+        self._policy_state: Optional[Dict[str, float]] = None
         self.queues: Dict[Phase, Deque[OpDescriptor]] = {  # guarded-by: _cv
             p: deque() for p in Phase}
         self.streams = HandleTable("stream")
@@ -206,6 +221,11 @@ class FlexDaemon:
 
     # ------------------------------------------------------------ enqueue
     def enqueue(self, op: OpDescriptor) -> Future:
+        with span(self._spans, "flex.enqueue", op=op,
+                  device=self.device_id):
+            return self._enqueue(op)
+
+    def _enqueue(self, op: OpDescriptor) -> Future:
         # fast-path rejection; the authoritative check re-runs under _cv
         # below, after the (lock-free) size/ref preamble
         # flexlint: ignore[lock-discipline] -- advisory read; re-checked under _cv
@@ -615,10 +635,12 @@ class FlexDaemon:
             else:
                 self._stream_inflight.pop(op.vstream, None)
             self._cv.notify_all()
-        if error is not None:
-            op.future.set_error(error)
-        else:
-            op.future.set_result(result)
+        with span(self._spans, "flex.callbacks", op=op,
+                  device=self.device_id):
+            if error is not None:
+                op.future.set_error(error)
+            else:
+                op.future.set_result(result)
         # The execution QUEUE frees only after the future's callbacks ran:
         # callbacks enqueue follow-up work (continuous batching), and the
         # threaded dispatcher must not race ahead of them and pick from a
@@ -781,6 +803,8 @@ class FlexDaemon:
     def start(self):
         with self._cv:
             self._stop = False
+        if isinstance(self.backend, RealBackend):
+            self._spans = self.timeline
         # one executor thread per execution queue: ops on different queues
         # (compute vs copy, or two compute queues) execute concurrently;
         # ops sharing a queue serialize
@@ -833,7 +857,26 @@ class FlexDaemon:
                     self._cv.wait(
                         0.001 if self._remote_edge_pending() else 0.1)
                 continue
+            if self._spans is not None:
+                self._trace_select(op, now)
             self._queue_workers[op.meta["_queue"]].put(op)
+
+    def _trace_select(self, op: OpDescriptor, start: float) -> None:
+        """Spans of the dispatcher: ``flex.select`` (``select_next``,
+        policy included), the start of ``flex.handoff`` (closed by the
+        queue worker), and a ``flex.policy`` counter whenever the
+        policy's state changed."""
+        tl = self._spans
+        tl.add("flex.select", start, time.monotonic(),
+               device=self.device_id, **op_ids(op))
+        state = self.policy.debug_state()
+        if state and state != self._policy_state:
+            self._policy_state = state
+            tl.counter("flex.policy", device=self.device_id, **state,
+                       decode_bw=self.profiler.decode_bandwidth_util(),
+                       prefill_backlog=self.backlog(Phase.PREFILL),
+                       decode_backlog=self.backlog(Phase.DECODE))
+        op.meta["_handoff"] = time.monotonic()
 
     def _queue_loop(self, qid: QueueId):
         q = self._queue_workers[qid]
@@ -841,13 +884,17 @@ class FlexDaemon:
             op = q.get()
             if op is None:
                 return
+            if self._spans is not None:
+                self._spans.add("flex.handoff", op.meta.pop("_handoff"),
+                                time.monotonic(), device=self.device_id,
+                                **op_ids(op))
             if op.op == OpType.LAUNCH:
                 try:
                     result = self.backend.execute(op)
                 except BaseException as e:  # propagate into the future
-                    self.mark_complete(op, self.backend.now(), error=e)
+                    self._complete(op, self.backend.now(), error=e)
                     continue
-                self.mark_complete(op, self.backend.now(), result)
+                self._complete(op, self.backend.now(), result)
             else:
                 # non-launch data-plane ops (memcpy, event markers): the
                 # effect itself is applied inside mark_complete.  A backend
@@ -857,7 +904,15 @@ class FlexDaemon:
                 pace = getattr(self.backend, "pace", None)
                 if pace is not None:
                     pace(op)
-                self.mark_complete(op, self.backend.now())
+                self._complete(op, self.backend.now())
+
+    def _complete(self, op: OpDescriptor, now: float, result: Any = None,
+                  error: Optional[BaseException] = None) -> None:
+        """``mark_complete`` on the threaded drive, inside the span
+        ``flex.complete`` (up to the queue's release)."""
+        with span(self._spans, "flex.complete", op=op,
+                  device=self.device_id):
+            self.mark_complete(op, now, result, error)
 
     def drain(self, timeout: float = 30.0):
         """Block until all queued work is done (thread mode)."""

@@ -1,7 +1,7 @@
 """Runtime profiler (paper §3.3): lightweight per-phase statistics.
 
 Collected from the interception layer and daemon:
-  * EWMA operator execution time and queue delay per phase,
+  * EWMA operator execution time per phase,
   * per-phase token throughput,
   * memory-bandwidth pressure of decode (bytes touched / exec time / HBM peak),
   * device utilization (busy fraction over a sliding horizon).
@@ -12,39 +12,34 @@ reads them to steer the prefill/decode dispatch ratio.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import itertools
 import json
 import os
 import threading
+import time
 from typing import Deque, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.core.api import OpDescriptor, OpType, Phase
 
 HBM_BW_BYTES = 819e9        # TPU v5e HBM bandwidth (DESIGN.md hardware model)
-PEAK_FLOPS = 197e12         # bf16 peak per chip
 
 
 @dataclasses.dataclass
 class PhaseStats:
     ewma_exec: float = 0.0          # seconds
-    ewma_queue_delay: float = 0.0
     ewma_bytes: float = 0.0         # bytes touched per op
-    ewma_flops: float = 0.0
     ops_completed: int = 0
     tokens_done: int = 0
-    busy_time: float = 0.0
 
     def bandwidth_util(self) -> float:
         """Estimated HBM pressure of this phase's ops (0..1)."""
         if self.ewma_exec <= 0:
             return 0.0
         return min(1.0, self.ewma_bytes / self.ewma_exec / HBM_BW_BYTES)
-
-    def compute_util(self) -> float:
-        if self.ewma_exec <= 0:
-            return 0.0
-        return min(1.0, self.ewma_flops / self.ewma_exec / PEAK_FLOPS)
 
 
 class Profiler:
@@ -63,14 +58,10 @@ class Profiler:
     def on_complete(self, op: OpDescriptor) -> None:
         s = self.stats[op.phase]
         s.ewma_exec = self._ewma(s.ewma_exec, op.exec_time)
-        s.ewma_queue_delay = self._ewma(s.ewma_queue_delay, op.queue_delay)
         if "bytes" in op.meta:
             s.ewma_bytes = self._ewma(s.ewma_bytes, float(op.meta["bytes"]))
-        if "flops" in op.meta:
-            s.ewma_flops = self._ewma(s.ewma_flops, float(op.meta["flops"]))
         s.ops_completed += 1
         s.tokens_done += int(op.meta.get("tokens", 0))
-        s.busy_time += op.exec_time
         if op.op == OpType.LAUNCH:
             self._busy_events.append((op.dispatch_time, op.complete_time))
 
@@ -85,18 +76,6 @@ class Profiler:
 
     def decode_bandwidth_util(self) -> float:
         return self.stats[Phase.DECODE].bandwidth_util()
-
-    def snapshot(self) -> Dict[str, Dict[str, float]]:
-        return {
-            p.value: {
-                "ewma_exec": s.ewma_exec,
-                "ewma_queue_delay": s.ewma_queue_delay,
-                "bandwidth_util": s.bandwidth_util(),
-                "compute_util": s.compute_util(),
-                "ops": s.ops_completed,
-                "tokens": s.tokens_done,
-            } for p, s in self.stats.items()
-        }
 
 
 # --------------------------------------------------------------- timeline
@@ -124,6 +103,13 @@ class Timeline:
     Perfetto: rows are (device, execution queue), one slice per op with
     dispatch→complete extents and the op's phase/type/meta in ``args``.
 
+    Beside the ops, the threaded drive over the real backend records
+    **host spans** (:meth:`span`, :meth:`add`) and **counters**
+    (:meth:`counter`) into the same list, on the same ``time.monotonic``
+    clock: a span is an ``X`` event on its thread's row (``tid`` is the
+    thread's name), a counter a ``C`` event.  Span names hold no ``:``,
+    so a reader that takes ``"<phase>:<op>"`` as an op never mistakes one.
+
     Capture is OFF unless ``FLEX_PROFILE=1`` — the hot path pays only a
     ``None`` check — and recording is one dict append under a lock, so
     turning it on perturbs (wall-clock) timing but never simulated time.
@@ -132,6 +118,8 @@ class Timeline:
     def __init__(self):
         self._lk = threading.Lock()
         self._events: List[dict] = []
+        # per thread: the op_id of each open span that carries one
+        self._local = threading.local()
 
     def record(self, device_id: int, op: OpDescriptor) -> None:
         q = op.meta.get("_queue")
@@ -147,11 +135,49 @@ class Timeline:
                      "queue_delay_us": max(op.queue_delay, 0.0) * 1e6},
         }
         for k in ("tokens", "bytes", "flops", "instance", "req_id",
-                  "ctx", "chunk"):
+                  "ctx", "chunk", "step"):
             if k in op.meta:
                 ev["args"][k] = op.meta[k]
         with self._lk:
             self._events.append(ev)
+
+    def span(self, name: str, *, device: int = 0, **args) -> "_Span":
+        """Context manager: one ``X`` event from enter to exit on this
+        thread's row of ``device``, with ``args``.  While open it also
+        holds a ``jax.profiler.TraceAnnotation(name)``, so the span shows
+        on its host thread in any ``jax.profiler`` trace.  A span given
+        no ``op_id`` takes that of the innermost span open around it on
+        its thread: work run by an op's completion names that op."""
+        return _Span(self, name, device, args)
+
+    def add(self, name: str, start: float, end: float, *, device: int = 0,
+            **args) -> None:
+        """Record a span whose ends were read elsewhere (``time.monotonic``
+        seconds), such as one that starts on another thread.  It shows in
+        the Chrome trace only."""
+        self._append_span(name, start, end, device, args)
+
+    def counter(self, name: str, *, device: int = 0, **values) -> None:
+        """Record a ``C`` event: each of ``values`` is one series of the
+        counter ``name`` on ``device``'s row, read now."""
+        ev = {"name": name, "ph": "C", "ts": time.monotonic() * 1e6,
+              "pid": device, "args": values}
+        with self._lk:
+            self._events.append(ev)
+
+    def _append_span(self, name: str, start: float, end: float,
+                     device: int, args: dict) -> None:
+        ev = {"name": name, "ph": "X", "ts": start * 1e6,
+              "dur": max(end - start, 0.0) * 1e6, "pid": device,
+              "tid": threading.current_thread().name, "args": args}
+        with self._lk:
+            self._events.append(ev)
+
+    def _open_ops(self) -> List[int]:
+        stack = getattr(self._local, "ops", None)
+        if stack is None:
+            stack = self._local.ops = []
+        return stack
 
     def events(self) -> List[dict]:
         with self._lk:
@@ -170,3 +196,62 @@ class Timeline:
             with open(path, "w") as f:
                 json.dump(doc, f)
         return path
+
+
+class _Span:
+    """One open :meth:`Timeline.span`."""
+
+    __slots__ = ("_tl", "_name", "_device", "_args", "_pushed", "_ann",
+                 "_t0")
+
+    def __init__(self, tl: Timeline, name: str, device: int, args: dict):
+        self._tl = tl
+        self._name = name
+        self._device = device
+        self._args = args
+
+    def __enter__(self) -> "_Span":
+        ops = self._tl._open_ops()
+        self._pushed = "op_id" in self._args
+        if self._pushed:
+            ops.append(self._args["op_id"])
+        elif ops:
+            self._args["op_id"] = ops[-1]
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.monotonic()
+        self._ann.__exit__(*exc)
+        if self._pushed:
+            self._tl._open_ops().pop()
+        self._tl._append_span(self._name, self._t0, t1, self._device,
+                              self._args)
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def op_ids(op: OpDescriptor) -> Dict[str, object]:
+    """What a host span of ``op`` names it by: its ``op_id``, and the
+    request (``req_id``) or decode ``step`` it runs for."""
+    ids: Dict[str, object] = {"op_id": op.op_id}
+    for k in ("req_id", "step"):
+        if k in op.meta:
+            ids[k] = op.meta[k]
+    return ids
+
+
+def span(timeline: Optional[Timeline], name: str, *,
+         op: Optional[OpDescriptor] = None, **args):
+    """``timeline.span(name, **args)``, named also by ``op``'s
+    :func:`op_ids` where given; or one shared do-nothing context when
+    ``timeline`` is None (``FLEX_PROFILE`` unset): the call sites of the
+    hot path then build no event and open no annotation."""
+    if timeline is None:
+        return _NO_SPAN
+    if op is not None:
+        args.update(op_ids(op))
+    return timeline.span(name, **args)

@@ -76,9 +76,9 @@ def _chips(n: int) -> list:
     return [chips[i % len(chips)] for i in range(n)]
 
 
-def _backend_for(backend, device_id: int, chips: list):
+def _backend_for(backend, device_id: int, chips: list, timeline=None):
     if backend is None:
-        return RealBackend(chips[device_id])
+        return RealBackend(chips[device_id], timeline, device_id)
     if callable(backend) and not hasattr(backend, "now"):
         return backend(device_id)           # factory: callable(device_id)
     return backend                          # shared (e.g. one sim clock)
@@ -318,7 +318,7 @@ def connect(mode: str = "flex", devices: int = 1, *,
             clients.append(PassthroughClient(RealBackend(chips[i])))
             daemons.append(None)
             continue
-        d = FlexDaemon(i, _backend_for(backend, i, chips),
+        d = FlexDaemon(i, _backend_for(backend, i, chips, timeline),
                        policy=_policy_for(policy, i), shared_events=shared,
                        queues=queues(i) if callable(queues) else queues,
                        sanitizer=sanitizer, timeline=timeline)

@@ -59,6 +59,7 @@ import jax
 import numpy as np
 
 from repro.core.api import Phase
+from repro.core.profiler import span
 from repro.core.session import connect
 # The engine consumes the sched policy plane by design; the layering rank
 # exists to ban the reverse direction (sched importing serving).
@@ -137,6 +138,7 @@ class _Replica:
         self.engine = engine
         self.index = index
         self.name = f"replica{index}"
+        self.dev_p, self.dev_d = p_dev, d_dev   # session device indices
         sess = engine.session
         self.client = sess.device(p_dev)      # prefill-side client
         self.daemon = sess.daemon(p_dev)
@@ -173,6 +175,7 @@ class _Replica:
         self.prefilling_count = 0               # admitted, prefill running
         self.active_count = 0
         self.decode_inflight = False
+        self.steps = 0                          # decode steps launched
         # routing view (ClusterPolicy duck-typing)
         self.failed = False
         self.ewma_step = 0.0
@@ -264,6 +267,8 @@ class RealEngine:
             self.session = connect(mode="flex", devices=self.n_replicas,
                                    policy=policy, instance="engine",
                                    queues=queues)
+        # host spans and counters (FLEX_PROFILE=1) — None means off
+        self._tl = self.session.timeline
         self.replicas: List[_Replica] = []
         for r in range(self.n_replicas):
             if mode == "disagg":
@@ -365,18 +370,32 @@ class RealEngine:
             # v6+ routing signature, called directly (the v5 two-argument
             # adapter was removed in v9; the real engine has no prefix
             # caches yet, so the context only carries clock and loads)
+            req = self.waiting_admission[i]
             rep = self.router.route_prefill(
-                self.waiting_admission[i], self.replicas,
+                req, self.replicas,
                 RouteContext(now=time.monotonic(),
                              loads={r.name: r.load()
                                     for r in self.replicas}))
-            if rep is None or not self.admission.admit(
-                    self._admission_view(rep, i)):
+            if rep is None:
                 return
-            req = self.waiting_admission.pop(i)
-            self.admission.on_admit(req)
-            rep.prefilling_count += 1
+            with span(self._tl, "engine.admit", device=rep.dev_p,
+                      req_id=req.req_id):
+                if not self.admission.admit(self._admission_view(rep, i)):
+                    return
+                self.waiting_admission.pop(i)
+                self.admission.on_admit(req)
+                rep.prefilling_count += 1
+            self._count_queue_locked(rep)
             self._launch_prefill(rep, req)
+
+    def _count_queue_locked(self, rep: _Replica) -> None:  # holds: _lock
+        """The ``engine.queue`` counter of ``rep`` (tracing on only)."""
+        if self._tl is not None:
+            self._tl.counter("engine.queue", device=rep.dev_d,
+                             waiting=len(self.waiting_admission),
+                             prefilling=rep.prefilling_count,
+                             decode_pending=len(rep.decode_pending),
+                             active=rep.active_count)
 
     def _reject_locked(self, req: Request) -> None:  # holds: _lock
         req.state = RequestState.REJECTED
@@ -390,9 +409,11 @@ class RealEngine:
     def _launch_prefill(self, rep: _Replica, req: Request) -> None:  # holds: _lock
         req.state = RequestState.PREFILLING
         req.instance = rep.name
-        toks = jax.device_put(
-            np.asarray(req.prompt_tokens, np.int32)[None, :], rep.chip_p)
-        cache = _empty_cache(self.model, 1, self.max_len, rep.chip_p)
+        with span(self._tl, "engine.prefill.stage", device=rep.dev_p,
+                  req_id=req.req_id):
+            toks = jax.device_put(
+                np.asarray(req.prompt_tokens, np.int32)[None, :], rep.chip_p)
+            cache = _empty_cache(self.model, 1, self.max_len, rep.chip_p)
         t0 = time.monotonic()
         fut = rep.client.launch(
             rep.next_prefill_stream(), _prefill_step, self.model,
@@ -411,7 +432,9 @@ class RealEngine:
                 rep.prefilling_count = max(0, rep.prefilling_count - 1)
                 self._fail_locked(req)
             return
-        tok = int(np.argmax(np.asarray(logits[0])))
+        with span(self._tl, "engine.prefill.readback", device=rep.dev_p,
+                  req_id=req.req_id):
+            tok = int(np.argmax(np.asarray(logits[0])))
         now = time.monotonic()
         with self._lock:
             rep.prefilling_count = max(0, rep.prefilling_count - 1)
@@ -518,7 +541,10 @@ class RealEngine:
             if rep.slot_req[slot] is not None:
                 continue
             req, single_cache, tok = rep.decode_pending.pop(0)
-            rep.slot_cache = _insert_slot(rep.slot_cache, single_cache, slot)
+            with span(self._tl, "engine.insert", device=rep.dev_d, slot=slot,
+                      req_id=req.req_id):
+                rep.slot_cache = _insert_slot(rep.slot_cache, single_cache,
+                                              slot)
             rep.slot_req[slot] = req
             rep.lengths[slot] = req.prompt_len
             rep.next_tokens[slot] = tok
@@ -530,13 +556,18 @@ class RealEngine:
         if rep.decode_inflight or rep.active_count == 0:
             return
         rep.decode_inflight = True
-        toks = jax.device_put(rep.next_tokens.copy(), rep.chip_d)
-        lens = jax.device_put(rep.lengths.copy(), rep.chip_d)
+        rep.steps += 1
+        with span(self._tl, "engine.decode.stage", device=rep.dev_d,
+                  step=rep.steps):
+            toks = jax.device_put(rep.next_tokens.copy(), rep.chip_d)
+            lens = jax.device_put(rep.lengths.copy(), rep.chip_d)
         t0 = time.monotonic()
+        # ctx: the mean context of the live sequences (empty slots hold 0)
         fut = rep.client_d.launch(
             rep.stream_d, _decode_step, self.model, rep.params_d, toks,
             rep.slot_cache, lens, phase=Phase.DECODE,
-            meta={"tokens": rep.active_count})
+            meta={"tokens": rep.active_count, "step": rep.steps,
+                  "ctx": float(rep.lengths.sum()) / rep.active_count})
         fut.add_done_callback(
             lambda f, rp=rep, t=t0: self._decode_done(rp, f, t))
 
@@ -559,7 +590,10 @@ class RealEngine:
                 self._ensure_decode_locked(rep)
             return
         now = time.monotonic()
-        toks = np.argmax(np.asarray(logits), axis=-1)
+        # rep.steps is this step's: the next launches from here on
+        with span(self._tl, "engine.decode.readback", device=rep.dev_d,
+                  step=rep.steps):
+            toks = np.argmax(np.asarray(logits), axis=-1)
         with self._lock:
             rep.slot_cache = new_cache
             rep.decode_inflight = False
@@ -578,6 +612,7 @@ class RealEngine:
                     rep.lengths[slot] = 0
                     rep.active_count -= 1
                     self._finish_locked(req)
+            self._count_queue_locked(rep)
             self._drain_admission_locked()
             self._fill_slots_locked(rep)
             self._ensure_decode_locked(rep)
