@@ -71,8 +71,28 @@ def tpots(reqs, w0: float, w1: float) -> List[float]:
     return out
 
 
+def itls(reqs, w0: float, w1: float) -> List[float]:
+    """Every gap between two consecutive tokens of one request, both
+    emitted inside the window, pooled over all requests."""
+    out = []
+    for r in reqs:
+        ts = [t for t in r.token_times if w0 <= t <= w1]
+        out.extend(b - a for a, b in zip(ts, ts[1:]))
+    return out
+
+
 def tokens_in(reqs, w0: float, w1: float) -> int:
+    """Every token emitted inside the window, whichever request it
+    belongs to."""
     return sum(1 for r in reqs for t in r.token_times if w0 <= t <= w1)
+
+
+def served_tokens_in(reqs, w0: float, w1: float) -> int:
+    """Tokens emitted inside the window by the requests due inside it.
+    Tokens of requests due before the window are left out: a faster
+    engine emits more of them before the window opens, so counting them
+    would read the same work, finished sooner, as lost throughput."""
+    return tokens_in(due_in(reqs, w0, w1), w0, w1)
 
 
 def decode_steps(reqs) -> Dict[float, List[Tuple[TimedRequest, int]]]:

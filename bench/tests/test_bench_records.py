@@ -1,10 +1,13 @@
 """The end-to-end metric arithmetic on synthetic request records."""
+import os
 import types
 
 import pytest
 
-from bench import records
+from bench import records, traffic
 from bench.records import TimedRequest
+
+BENCH = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 
 def req(due, times, prompt_len=10):
@@ -60,9 +63,80 @@ def test_tpot_counts_only_gaps_inside_the_window():
     assert records.tpots(rs, 10.0, 20.0) == pytest.approx([0.15, 0.5])
 
 
+def test_itl_pools_every_gap_inside_the_window():
+    rs = [req(0.0, [9.0, 10.0, 10.1, 10.3, 20.5]),   # two gaps inside
+          req(0.0, [15.0]),                          # one token: no gap
+          req(11.0, [12.0, 12.5, 13.0, 13.5])]       # three gaps
+    assert sorted(records.itls(rs, 10.0, 20.0)) == \
+        pytest.approx([0.1, 0.2, 0.5, 0.5, 0.5])
+
+
+def test_itl_p95_shrugs_off_a_stall_that_moves_the_tpot_p90():
+    """A 1 s stall in the middle of the window lengthens one gap of each
+    of the 8 requests then decoding: their mean gaps pass the p90 over
+    requests, while the pooled p95 over gaps stays at the step time."""
+    def run(stall):
+        rs = []
+        for i in range(40):
+            t0 = 10.0 + 0.2 * i
+            ts = [t0 + 0.02 * k for k in range(50)]
+            if i >= 32:   # decoding when the stall comes
+                ts = [t + stall if t > ts[0] else t for t in ts]
+            rs.append(req(t0, ts))
+        return rs
+    calm, stalled = run(0.0), run(1.0)
+    tpot = [records.quantile(records.tpots(rs, 10.0, 30.0), 0.9)
+            for rs in (calm, stalled)]
+    itl = [records.quantile(records.itls(rs, 10.0, 30.0), 0.95)
+           for rs in (calm, stalled)]
+    assert tpot[1] > 1.5 * tpot[0]
+    assert itl[1] == pytest.approx(itl[0])
+
+
 def test_tokens_in_the_window_include_unfinished_requests():
     rs = [req(0.0, [9.0, 10.0, 10.1]), req(0.0, [19.9, 20.0, 20.1])]
     assert records.tokens_in(rs, 10.0, 20.0) == 4
+
+
+def test_served_tokens_count_only_the_windows_own_requests():
+    rs = [req(9.0, [9.5, 10.0, 10.1]),         # due before the window
+          req(12.0, [12.1, 19.9, 20.0, 20.1]),  # due inside, unfinished
+          req(20.0, [20.0])]                    # due at the window's end
+    assert records.tokens_in(rs, 10.0, 20.0) == 6
+    assert records.served_tokens_in(rs, 10.0, 20.0) == 3
+
+
+def chat_at(tpot, ttft=0.025):
+    """The chat mix's fixed trace, each request served at a fixed TTFT and
+    a constant TPOT, with no batching; returns (requests, w0, w1)."""
+    mix = traffic.load_mix(os.path.join(BENCH, "traffic", "chat.json"))
+    rs = []
+    for p in traffic.plan(mix, 51):
+        r = req(p.due, [])
+        r.token_times = [p.due + ttft + i * tpot
+                         for i in range(p.output_len)]
+        rs.append(r)
+    return rs, mix["lead_in_s"], mix["lead_in_s"] + 51
+
+
+TPOTS = [0.008, 0.012, 0.017, 0.022, 0.024, 0.030, 0.040]
+
+
+@pytest.mark.parametrize("faster,slower", zip(TPOTS, TPOTS[1:]),
+                         ids=lambda t: f"{1000 * t:g}ms")
+def test_served_tokens_never_fall_as_decode_gets_faster(faster, slower):
+    fast, slow = (records.served_tokens_in(*chat_at(t))
+                  for t in (faster, slower))
+    assert fast >= slow
+
+
+def test_every_token_in_the_window_falls_as_decode_gets_faster():
+    """The artifact ``served_tokens_in`` removes: the lead-in ends with two
+    long answers, and a faster decode emits more of them before the window
+    opens than its own requests gain at the window's end."""
+    counts = {tpot: records.tokens_in(*chat_at(tpot))
+              for tpot in (0.012, 0.022)}
+    assert counts[0.012] < counts[0.022]
 
 
 def test_quantile_interpolates():
