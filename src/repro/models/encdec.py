@@ -119,12 +119,15 @@ def decode_forward(cfg: ModelConfig, params: Dict, x, enc_out, *,
 
 def decode_step(cfg: ModelConfig, params: Dict, x, *, lengths,
                 self_caches, cross_cache):
-    """One decoder token.  x: [B, 1, d] (embedded + positioned)."""
-    def step(x, xs):
-        p, cache, ck, cv = xs
+    """One decoder token.  x: [B, 1, d] (embedded + positioned).  The self
+    caches ride the layer scan's carry and are updated in place, as in
+    ``transformer.stack_decode``: callers use the returned ones."""
+    def step(carry, xs):
+        x, caches = carry
+        layer, p, ck, cv = xs
         h = L.apply_norm(cfg, p["norm_self"], x)
-        mix, nc = L.attention_decode(cfg, p["self_attn"], h, lengths,
-                                     cache=cache)
+        mix, caches = L.attention_decode(cfg, p["self_attn"], h, lengths,
+                                         cache=caches, layer=layer)
         x = x + mix
         h = L.apply_norm(cfg, p["norm_cross"], x)
         q = jnp.einsum("bsd,dhk->bshk", h, p["cross_attn"]["wq"])
@@ -134,10 +137,12 @@ def decode_step(cfg: ModelConfig, params: Dict, x, *, lengths,
         x = x + jnp.einsum("bshk,hkd->bsd", o, p["cross_attn"]["wo"])
         h = L.apply_norm(cfg, p["norm_ffn"], x)
         x = x + L.apply_mlp(cfg, p["mlp"], h)
-        return x, nc
+        return (x, caches), None
 
-    x, new_caches = jax.lax.scan(
-        step, x, (params["dec_blocks"], self_caches,
-                  cross_cache["cross_k"], cross_cache["cross_v"]))
+    layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    (x, new_caches), _ = jax.lax.scan(
+        step, (x, self_caches),
+        (layers, params["dec_blocks"], cross_cache["cross_k"],
+         cross_cache["cross_v"]))
     x = L.apply_norm(cfg, params["norm_dec_final"], x)
     return x, new_caches

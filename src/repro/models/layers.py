@@ -371,25 +371,27 @@ def kv_write_prefill(cache: Dict, k, v) -> Dict:
     return new
 
 
-def kv_write_decode(cache: Dict, k, v, lengths) -> Dict:
-    """Scatter one token per sequence at slot ``lengths % T``.
+def layer_of(cache: Dict, layer) -> Dict:
+    """Layer ``layer`` of a cache whose leaves stack every layer on axis 0."""
+    return {n: jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+            for n, c in cache.items()}
+
+
+def kv_write_decode(cache: Dict, k, v, lengths, layer) -> Dict:
+    """Write one token per sequence into layer ``layer`` of a stacked cache
+    (leaves ``[L, B, T, ...]``), at slot ``lengths % T``: one row per
+    sequence, nothing else is copied.
     k/v: [B, 1, KVH, D]; lengths: [B] (length BEFORE this token)."""
     B = k.shape[0]
-    T = cache["k"].shape[1]
+    T = cache["k"].shape[2]
     slots = (lengths % T).astype(jnp.int32)
     bidx = jnp.arange(B, dtype=jnp.int32)
-    new = dict(cache)
+    rows = {"k": k[:, 0], "v": v[:, 0]}
     if "k_scale" in cache:
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        new["k"] = cache["k"].at[bidx, slots].set(kq[:, 0])
-        new["v"] = cache["v"].at[bidx, slots].set(vq[:, 0])
-        new["k_scale"] = cache["k_scale"].at[bidx, slots].set(ks[:, 0])
-        new["v_scale"] = cache["v_scale"].at[bidx, slots].set(vs[:, 0])
-    else:
-        new["k"] = cache["k"].at[bidx, slots].set(k[:, 0].astype(cache["k"].dtype))
-        new["v"] = cache["v"].at[bidx, slots].set(v[:, 0].astype(cache["v"].dtype))
-    return new
+        rows["k"], rows["k_scale"] = _quantize_kv(rows["k"])
+        rows["v"], rows["v_scale"] = _quantize_kv(rows["v"])
+    return {n: cache[n].at[layer, bidx, slots].set(r.astype(cache[n].dtype))
+            for n, r in rows.items()}
 
 
 # --------------------------------------------------------------------------
@@ -435,10 +437,12 @@ def attention_forward(cfg: ModelConfig, p: Dict, x, positions, *,
 
 
 def attention_decode(cfg: ModelConfig, p: Dict, x, lengths, *,
-                     window: int = 0, cache: Dict,
+                     window: int = 0, cache: Dict, layer,
                      cos=None, sin=None):
     """One-token decode.  x: [B, 1, d_model]; lengths: [B] BEFORE this token.
-    Returns (out, new_cache)."""
+    ``cache`` stacks every layer's K/V on axis 0 and this layer is
+    ``layer``: the token's row is written there and the layer is read in
+    place.  Returns (out, cache)."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -448,8 +452,8 @@ def attention_decode(cfg: ModelConfig, p: Dict, x, lengths, *,
     if cos is not None:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-    cache = kv_write_decode(cache, k, v, lengths)
-    kd, vd = kv_read(cache, x.dtype)
+    cache = kv_write_decode(cache, k, v, lengths, layer)
+    kd, vd = kv_read(layer_of(cache, layer), x.dtype)
     T = kd.shape[1]
     is_ring = window > 0 and T <= window
     if is_ring:

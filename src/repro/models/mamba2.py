@@ -15,7 +15,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import Param, shard_act
-from repro.models.layers import dense_param, ones_param, zeros_param
+from repro.models.layers import (dense_param, layer_of, ones_param,
+                                 zeros_param)
 
 
 def _dims(cfg: ModelConfig):
@@ -223,9 +224,12 @@ def mamba_forward(cfg: ModelConfig, p: Dict, x, cache: Optional[Dict] = None):
     return shard_act(out, "batch", None, "act_embed"), new_cache
 
 
-def mamba_decode(cfg: ModelConfig, p: Dict, x, cache: Dict):
-    """One-token decode.  x: [B, 1, d].  Returns (y [B,1,d], new_cache)."""
+def mamba_decode(cfg: ModelConfig, p: Dict, x, cache: Dict, layer):
+    """One-token decode.  x: [B, 1, d].  ``cache`` stacks every layer's
+    state on axis 0 and this layer is ``layer``: its state is read there and
+    replaced whole.  Returns (y [B,1,d], cache)."""
     s = cfg.ssm
+    state = layer_of(cache, layer)
     d_inner, nheads, conv_dim = _dims(cfg)
     B_ = x.shape[0]
 
@@ -234,7 +238,7 @@ def mamba_decode(cfg: ModelConfig, p: Dict, x, cache: Dict):
 
     # conv ring update
     conv_hist = jnp.concatenate(
-        [cache["conv"], xBC.astype(jnp.float32)[:, None, :]], axis=1)
+        [state["conv"], xBC.astype(jnp.float32)[:, None, :]], axis=1)
     w = p["conv_w"].astype(jnp.float32)                      # [W, C]
     conv_out = jnp.einsum("bwc,wc->bc", conv_hist, w) + p["conv_b"][None]
     xBC = jax.nn.silu(conv_out).astype(x.dtype)
@@ -247,7 +251,7 @@ def mamba_decode(cfg: ModelConfig, p: Dict, x, cache: Dict):
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"][None, :])
     A = -jnp.exp(p["A_log"])
 
-    y, new_state = ssd_decode_step(cache["ssm"], xs, dt, A, Bmat, Cmat)
+    y, new_ssm = ssd_decode_step(state["ssm"], xs, dt, A, Bmat, Cmat)
     y = y + p["D"][None, :, None] * xs
     y = y.reshape(B_, d_inner)
 
@@ -258,5 +262,6 @@ def mamba_decode(cfg: ModelConfig, p: Dict, x, cache: Dict):
          * p["norm_scale"]).astype(x.dtype)
     out = jnp.einsum("be,ed->bd", y, p["out_proj"])[:, None, :]
 
-    new_cache = {"conv": conv_hist[:, 1:], "ssm": new_state}
-    return out, new_cache
+    new = {"conv": conv_hist[:, 1:], "ssm": new_ssm}
+    return out, {n: jax.lax.dynamic_update_index_in_dim(c, new[n], layer, 0)
+                 for n, c in cache.items()}

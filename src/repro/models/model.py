@@ -253,7 +253,9 @@ class Model:
     # ------------------------------------------------------------- decode
     def decode(self, params, tokens, cache, lengths):
         """One token per sequence.  tokens: [B] int32; lengths: [B] current
-        cache length (count of tokens already in the cache)."""
+        cache length (count of tokens already in the cache).  The cache is
+        updated in place (``transformer.stack_decode``): under a jit that
+        donates it, use the returned cache, not the one passed in."""
         cfg = self.cfg
         x = self._embed(params, tokens[:, None])
         if cfg.is_encdec:

@@ -137,13 +137,15 @@ def stack_cache_axes(cfg: ModelConfig, max_len: int) -> List[Dict]:
 
 def _apply_block(cfg: ModelConfig, desc: BlockDesc, p: Dict, x, *,
                  mode: str, positions=None, lengths=None, cache=None,
-                 cos=None, sin=None, dropless: bool = False):
-    """mode: 'full' (train/prefill) or 'decode'. Returns (x, new_cache, aux)."""
+                 layer=None, cos=None, sin=None, dropless: bool = False):
+    """mode: 'full' (train/prefill) or 'decode'. Returns (x, new_cache, aux).
+    In decode, ``cache`` is the whole stack of this pattern position and
+    ``layer`` the repeat to update in it."""
     aux = jnp.zeros((), jnp.float32)
     h = L.apply_norm(cfg, p["norm_mixer"], x)
     if desc.mixer == "mamba":
         if mode == "decode":
-            mix, new_cache = M.mamba_decode(cfg, p["mamba"], h, cache)
+            mix, new_cache = M.mamba_decode(cfg, p["mamba"], h, cache, layer)
         else:
             mix, new_cache = M.mamba_forward(cfg, p["mamba"], h, cache)
     else:
@@ -151,7 +153,7 @@ def _apply_block(cfg: ModelConfig, desc: BlockDesc, p: Dict, x, *,
         if mode == "decode":
             mix, new_cache = L.attention_decode(
                 cfg, p["attn"], h, lengths, window=window, cache=cache,
-                cos=cos, sin=sin)
+                layer=layer, cos=cos, sin=sin)
         else:
             mix, new_cache = L.attention_forward(
                 cfg, p["attn"], h, positions, causal=True, window=window,
@@ -215,7 +217,14 @@ def stack_forward(cfg: ModelConfig, stacked_params: List[Dict], x, *,
 
 def stack_decode(cfg: ModelConfig, stacked_params: List[Dict], x, *,
                  lengths, caches: List):
-    """One-token decode.  x: [B, 1, d].  Returns (x, new_caches, aux)."""
+    """One-token decode.  x: [B, 1, d].  Returns (x, new_caches, aux).
+
+    The caches ride the layer scan's carry and are updated in place: each
+    attention layer writes one K/V row per sequence and reads its own
+    layer where it lies, each state layer replaces its state.  Under a jit
+    that donates them (the serving engine's ``_decode_step``), the returned
+    caches take the given ones' buffers: the caches passed in are consumed,
+    and callers must use the returned ones."""
     pat = block_pattern(cfg)
     cos = sin = None
     if not cfg.attention_free and cfg.pos_emb.value in ("rope", "mrope"):
@@ -223,17 +232,19 @@ def stack_decode(cfg: ModelConfig, stacked_params: List[Dict], x, *,
         cos, sin = L.positional_cos_sin(cfg, pos)
 
     def step(carry, xs):
-        x, aux = carry
-        params_j, caches_j = xs
-        new_caches_j = []
-        for desc, p, c in zip(pat, params_j, caches_j):
-            x, nc, a = _apply_block(cfg, desc, p, x, mode="decode",
-                                    lengths=lengths, cache=c,
-                                    cos=cos, sin=sin)
-            new_caches_j.append(nc if nc is not None else {})
+        x, aux, caches = carry
+        layer, params_j = xs
+        new_caches = []
+        for desc, p, c in zip(pat, params_j, caches):
+            x, c, a = _apply_block(cfg, desc, p, x, mode="decode",
+                                   lengths=lengths, cache=c, layer=layer,
+                                   cos=cos, sin=sin)
+            new_caches.append(c)
             aux = aux + a
-        return (x, aux), tuple(new_caches_j)
+        return (x, aux, tuple(new_caches)), None
 
-    (x, aux), new_caches = jax.lax.scan(
-        step, (x, jnp.zeros((), jnp.float32)), (stacked_params, caches))
-    return x, list(new_caches), aux
+    layers = jnp.arange(num_repeats(cfg), dtype=jnp.int32)
+    (x, aux, caches), _ = jax.lax.scan(
+        step, (x, jnp.zeros((), jnp.float32), tuple(caches)),
+        (layers, stacked_params))
+    return x, list(caches), aux

@@ -82,8 +82,11 @@ def _prefill_step(model: Model, params, toks, cache):
     return model.prefill(params, {"tokens": toks}, cache)
 
 
-@functools.partial(jax.jit, static_argnums=0)
+@functools.partial(jax.jit, static_argnums=0, donate_argnums=3)
 def _decode_step(model: Model, params, toks, cache, lens):
+    """One decode step over the slot cache, written in place: ``cache`` is
+    donated, so the call consumes it (its arrays are deleted, also when the
+    step fails while it runs) and callers must use the returned cache."""
     return model.decode(params, toks, cache, lens)
 
 
@@ -532,8 +535,8 @@ class RealEngine:
     # ------------------------------------------------------------- decode
     def _fill_slots_locked(self, rep: _Replica):  # holds: _lock
         if rep.decode_inflight:
-            # the in-flight decode holds a snapshot of slot_cache; inserting
-            # now would be overwritten when it completes (lost update)
+            # the in-flight decode consumed slot_cache; the one it returns
+            # is the only cache until it completes
             return
         for slot in range(self.max_num_seqs):
             if not rep.decode_pending:
@@ -568,6 +571,7 @@ class RealEngine:
             rep.slot_cache, lens, phase=Phase.DECODE,
             meta={"tokens": rep.active_count, "step": rep.steps,
                   "ctx": float(rep.lengths.sum()) / rep.active_count})
+        rep.slot_cache = None       # donated: _decode_done stores the next
         fut.add_done_callback(
             lambda f, rp=rep, t=t0: self._decode_done(rp, f, t))
 
@@ -578,7 +582,10 @@ class RealEngine:
             _log.exception("decode step failed on %s", rep.name)
             with self._lock:
                 # the step's requests end FAILED; requests still waiting
-                # for a slot go on to the next step
+                # for a slot go on to the next step, on a fresh cache in
+                # place of the one the step consumed
+                rep.slot_cache = _empty_cache(self.model, self.max_num_seqs,
+                                              self.max_len, rep.chip_d)
                 rep.decode_inflight = False
                 for slot, req in enumerate(rep.slot_req):
                     if req is not None:

@@ -1,5 +1,7 @@
 """RealEngine integration: determinism across scheduling modes + Table-4
 behaviour (dynamic PD slashes TTFT under backlog, same outputs)."""
+import functools
+
 import jax
 import numpy as np
 import pytest
@@ -113,6 +115,47 @@ def test_failing_decode_step_fails_its_requests(setup, monkeypatch, mode):
     assert res["failed"] == len(reqs)
     assert all(r.state == RequestState.FAILED and r.finish_time > 0
                for r in reqs)
+
+
+def _fault(logits):
+    raise RuntimeError("decode fault inside the step")
+
+
+@functools.partial(jax.jit, static_argnums=0, donate_argnums=3)
+def _failing_step(model, params, toks, cache, lens):
+    """The engine's decode step, failing while it runs: after its cache
+    was donated."""
+    logits, new = model.decode(params, toks, cache, lens)
+    return jax.pure_callback(_fault, logits, logits), new
+
+
+@pytest.mark.parametrize("mode", ["passthrough", "dynamic_pd"])
+def test_decode_step_failing_after_donation_keeps_the_replica_serving(
+        setup, monkeypatch, mode):
+    """A decode step that fails inside the jitted call has consumed the
+    slot cache; its requests end FAILED and the replica serves the next
+    requests on a fresh cache, with the reference's tokens."""
+    given, real = [], E._decode_step
+
+    def first_fails(model, params, toks, cache, lens):
+        given.append(cache)
+        step = _failing_step if len(given) == 1 else real
+        return step(model, params, toks, cache, lens)
+
+    monkeypatch.setattr(E, "_decode_step", first_fails)
+    cfg, model, params = setup
+    eng = RealEngine(model, params, mode=mode, max_num_seqs=2, max_len=64)
+    try:
+        first = eng.run(mk_requests(cfg, n=2, gap=0.0), timeout=60)
+        reqs = mk_requests(cfg, n=3)
+        res = eng.run(reqs, timeout=120)
+    finally:
+        eng.shutdown()
+    assert first["failed"] >= 1
+    assert all(x.is_deleted() for x in jax.tree.leaves(given[0]))
+    assert res["completed"] == len(reqs)
+    assert [r.output_tokens for r in reqs] == \
+        reference_outputs(cfg, model, params, reqs)
 
 
 def _devices_of(tree):

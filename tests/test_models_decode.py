@@ -1,6 +1,9 @@
 """Decode-path consistency: prefill + step-by-step decode must match the
-teacher-forced forward pass (the serving engine's correctness foundation)."""
+teacher-forced forward pass (the serving engine's correctness foundation).
+Decoding goes through the engine's jitted ``_decode_step``, which donates
+the cache and updates it in place."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -10,14 +13,16 @@ import pytest
 from repro.configs import get_config, list_archs
 from repro.distributed.sharding import unbox
 from repro.models import build_model
+from repro.serving.engine import _decode_step
 
 B, S, P = 2, 24, 16
 
 
-def _consistency(arch, rng_key, tol):
+def _consistency(arch, rng_key, tol, kv_cache_dtype="bfloat16"):
     cfg = get_config(arch).reduced()
-    # exact-match caches for the comparison (int8 adds quantization noise)
-    cfg = dataclasses.replace(cfg, kv_cache_dtype="bfloat16")
+    # exact-match caches for the comparison unless asked otherwise (int8
+    # adds quantization noise)
+    cfg = dataclasses.replace(cfg, kv_cache_dtype=kv_cache_dtype)
     model = build_model(cfg)
     params = unbox(model.init(rng_key))
     if cfg.is_encdec:
@@ -62,8 +67,11 @@ def _consistency(arch, rng_key, tol):
     errs = [close(lg, full[:, P - 1])]
     agree = [agree_step(lg, full[:, P - 1])]
     for t in range(P, S):
-        lg, cache = model.decode(params, toks[:, t], cache,
+        given = cache
+        lg, cache = _decode_step(model, params, toks[:, t], cache,
                                  jnp.full((B,), t, jnp.int32))
+        # the step consumed the cache it was given; the next uses the new
+        assert all(x.is_deleted() for x in jax.tree.leaves(given))
         errs.append(close(lg, full[:, t]))
         agree.append(agree_step(lg, full[:, t]))
     # distributions must be near-identical at nearly every step (bf16 noise
@@ -74,13 +82,43 @@ def _consistency(arch, rng_key, tol):
     assert np.mean(agree) >= min_agree, f"argmax agreement {np.mean(agree)}"
 
 
-@pytest.mark.parametrize("arch", list_archs())
-def test_prefill_decode_matches_forward(arch, rng_key):
+# every arch with a bfloat16 cache, and each arch with attention again with
+# the int8 cache (values and per-row scales written one row per step)
+CASES = [pytest.param(a, "bfloat16", id=a) for a in list_archs()] + [
+    pytest.param(a, "int8", id=f"{a}-int8") for a in list_archs()
+    if not get_config(a).attention_free]
+
+
+@pytest.mark.parametrize("arch,kv_cache_dtype", CASES)
+def test_prefill_decode_matches_forward(arch, kv_cache_dtype, rng_key):
     # grok's former xfail is resolved by tie-aware agreement scoring (see
     # agree_step): its softcapped attention logits made genuine near-ties
     # flip between the batched-forward and step-decode reduction orders.
     tol = 0.05
-    _consistency(arch, rng_key, tol)
+    _consistency(arch, rng_key, tol, kv_cache_dtype)
+
+
+def _aliased_params(hlo: str):
+    """Parameter numbers the compiled module aliases to an output."""
+    m = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+    return [int(n) for n in re.findall(r"\((\d+), \{", m.group(1))] \
+        if m else []
+
+
+@pytest.mark.parametrize("arch", list_archs())
+def test_decode_step_donates_the_cache(arch, rng_key):
+    """Every cache leaf is aliased to an output of the compiled step, so
+    the step writes the cache in place and holds no second copy."""
+    cfg = get_config(arch).reduced()
+    model = build_model(cfg)
+    params = unbox(model.init(rng_key))
+    cache = model.init_cache(B, S, enc_len=12)
+    lens = jnp.full((B,), P, jnp.int32)
+    compiled = _decode_step.lower(model, params, lens, cache, lens).compile()
+    leaves = jax.tree.leaves(cache)
+    assert len(_aliased_params(compiled.as_text())) == len(leaves)
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        sum(x.nbytes for x in leaves)
 
 
 def test_mamba2_decode_exact(rng_key):
