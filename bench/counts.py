@@ -2,18 +2,10 @@
 
 Kept with the benchmark, not taken from the program, so that a change to
 the program cannot change what a roofline or ``mfu`` is measured against.
-``spec`` is a configuration file of ``bench/configs`` (its ``model`` part).
-
-What is counted:
-
-* decode step: every weight read once at the weights' dtype, each active
-  sequence's live KV read at its own length (for windowed layers at most
-  the window), one new KV row written per active sequence, and the fp32
-  logits written; FLOPs are 2 x matmul parameters x active sequences plus
-  attention over each live context;
-* prefill: 2 x matmul parameters x prompt tokens, plus causal attention
-  over the window, plus the last token's logits; bytes are the weights
-  and the KV written.
+Each family's module counts its own steps (``decode_step`` and
+``prefill`` of ``bench/arch/<arch>.py``, from the ``model`` part of a
+configuration file of ``bench/configs``); this module holds what they
+share.
 
 Not counted: empty slots, positions past a sequence's length, and copies
 the program makes that the algorithm does not need (an undonated cache).
@@ -21,86 +13,10 @@ So a program that stops making them can only raise its share.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, Tuple
+from typing import Dict
 
 DTYPE_BYTES = {"bfloat16": 2, "float32": 4}
 LOGIT_BYTES = 4
-
-
-def _gated(m: Dict) -> bool:
-    return m["activation"] in ("swiglu", "geglu")
-
-
-def layer_matmul_params(m: Dict) -> int:
-    """Matmul parameters of one transformer block."""
-    d, h, kvh, hd, f = (m["d_model"], m["num_heads"], m["num_kv_heads"],
-                        m["head_dim"], m["d_ff"])
-    attn = d * h * hd + 2 * d * kvh * hd + h * hd * d
-    mlp = d * f * (3 if _gated(m) else 2)
-    return attn + mlp
-
-
-def head_params(m: Dict) -> int:
-    return m["d_model"] * m["vocab_size"]
-
-
-def weight_bytes(m: Dict) -> int:
-    """Every weight once: the blocks, the embedding table (which is also
-    the output head where the embeddings are tied), an untied head, and
-    the norms' parameters (fp32)."""
-    wb = DTYPE_BYTES[m["param_dtype"]]
-    n = m["num_layers"] * layer_matmul_params(m) + head_params(m)
-    if not m["tie_embeddings"]:
-        n += head_params(m)
-    norms = {"layernorm": 2, "rmsnorm": 1, "nonparam_ln": 0}[m["norm"]]
-    norm_bytes = norms * m["d_model"] * (2 * m["num_layers"] + 1) * 4
-    return n * wb + norm_bytes
-
-
-def kv_row_bytes(m: Dict) -> int:
-    """K and V of one position in one layer."""
-    return 2 * m["num_kv_heads"] * m["head_dim"] * DTYPE_BYTES[m["kv_dtype"]]
-
-
-def _live(m: Dict, ctx: int) -> int:
-    w = m.get("sliding_window") or 0
-    return min(ctx, w) if w else ctx
-
-
-def _attn_flops(m: Dict, positions: int) -> int:
-    """QK^T and PV of one query over ``positions`` keys, all layers."""
-    return 4 * m["num_heads"] * m["head_dim"] * positions * m["num_layers"]
-
-
-def decode_step(m: Dict, contexts: Iterable[int]) -> Tuple[float, float]:
-    """(FLOPs, bytes) of one decode step.  ``contexts`` holds each active
-    sequence's length including the token this step writes."""
-    ctx = [int(c) for c in contexts]
-    b = len(ctx)
-    per_token = 2 * (m["num_layers"] * layer_matmul_params(m)
-                     + head_params(m))
-    flops = per_token * b + sum(_attn_flops(m, _live(m, c)) for c in ctx)
-    row = kv_row_bytes(m) * m["num_layers"]
-    # the row written this step is read too: it is part of the live context
-    kv_read = sum(_live(m, c) for c in ctx) * row
-    byts = (weight_bytes(m) + kv_read + b * row
-            + b * m["vocab_size"] * LOGIT_BYTES)
-    return float(flops), float(byts)
-
-
-def prefill(m: Dict, prompt_len: int) -> Tuple[float, float]:
-    """(FLOPs, bytes) of one single-sequence prefill of ``prompt_len``."""
-    s = int(prompt_len)
-    per_token = 2 * m["num_layers"] * layer_matmul_params(m)
-    w = m.get("sliding_window") or 0
-    if w and s > w:
-        keys = w * (w + 1) // 2 + (s - w) * w
-    else:
-        keys = s * (s + 1) // 2
-    flops = per_token * s + _attn_flops(m, keys) + 2 * head_params(m)
-    written = _live(m, s) * kv_row_bytes(m) * m["num_layers"]
-    byts = weight_bytes(m) + written + m["vocab_size"] * LOGIT_BYTES
-    return float(flops), float(byts)
 
 
 def least_time(flops: float, byts: float, peak: Dict) -> float:

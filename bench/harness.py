@@ -29,9 +29,6 @@ STOP_WAIT_S = 120.0     # the most a run waits for the engine to empty
 WARM_UP_INDEX = 1 << 30  # prompt ids of warm-up requests, apart from traffic
 WARM_UP_WAIT_S = 300.0   # the most one warm-up request may take (compiling)
 
-# program-side names of the configuration file's model keys
-ACTIVATION = {"swiglu": "swiglu", "gelu_tanh": "gelu"}
-
 
 def log(msg: str) -> None:
     print(f"bench: {msg}", file=sys.stderr, flush=True)
@@ -95,23 +92,6 @@ class Run:
                 if e["name"] == f"{phase}:launch"]
 
 
-def program_config(cfg: Dict):
-    """The program's ModelConfig for a configuration file: the registry's
-    entry with every size the file states put in."""
-    from repro.configs import Activation, Norm, get_config
-    m = cfg["model"]
-    base = get_config(cfg["program"]["config"])
-    return dataclasses.replace(
-        base, num_layers=m["num_layers"], d_model=m["d_model"],
-        num_heads=m["num_heads"], num_kv_heads=m["num_kv_heads"],
-        head_dim=m["head_dim"], d_ff=m["d_ff"], vocab_size=m["vocab_size"],
-        activation=Activation(ACTIVATION[m["activation"]]),
-        norm=Norm(m["norm"]), rope_theta=m["rope_theta"],
-        sliding_window=m.get("sliding_window") or 0,
-        tie_embeddings=m["tie_embeddings"], param_dtype=m["param_dtype"],
-        kv_cache_dtype=m["kv_dtype"])
-
-
 def peak_for(kind: str) -> Dict:
     table = load_json(os.path.join(BENCH, "peaks.json"))
     if kind not in table["devices"]:
@@ -137,7 +117,7 @@ class Cell:
                  require_tpu: bool = True, cache: bool = True,
                  trace_dir: Optional[str] = None):
         import jax
-        from bench import traffic, weights
+        from bench import arch, traffic, weights
         self.jax = jax
         self.cell = cell
         self.config = load_json(config_path)
@@ -172,13 +152,14 @@ class Cell:
 
         from repro.distributed.sharding import unbox
         from repro.models import build_model
-        self.model = build_model(program_config(self.config))
-        self.params = weights.make_params(self.model_spec, self.seed,
-                                          devs[0])
+        self.model = build_model(
+            arch.of(self.model_spec).program_config(self.config))
         weights.check_layout(
-            jax.eval_shape(lambda p: p, self.params),
+            weights.layout(self.model_spec),
             jax.eval_shape(lambda: unbox(self.model.init(
                 jax.random.PRNGKey(0)))))
+        self.params = weights.make_params(self.model_spec, self.seed,
+                                          devs[0])
         self.engine = self._engine()
         self.grid = traffic.prompt_grid(self.mix)
 

@@ -6,7 +6,7 @@ from the token times, which the engine stamps alike for every sequence of
 one step, just after the op completes."""
 import bisect
 
-from bench import counts, trace
+from bench import arch, counts, trace
 from bench.records import decode_steps
 
 MODULE = "jit__decode_step"
@@ -15,6 +15,7 @@ MODULE = "jit__decode_step"
 def read(run):
     if run.device is None or run.peak is None:
         return None
+    a = arch.of(run.model)
     steps = decode_steps(run.requests)
     times = sorted(steps)
     least = dev = 0.0
@@ -25,7 +26,7 @@ def read(run):
         if i == len(times) or times[i] - done > 0.5:
             continue
         ctx = [c for _, c in steps[times[i]]]
-        least += counts.least_time(*counts.decode_step(run.model, ctx),
+        least += counts.least_time(*a.decode_step(run.model, ctx),
                                    run.peak)
         dev += secs
     return 100.0 * least / dev if dev > 0 else None

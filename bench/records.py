@@ -7,7 +7,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Tuple
 
-from bench import counts
+from bench import arch
 from repro.serving.request import Request
 
 
@@ -114,13 +114,14 @@ def step_mfu(run) -> Optional[float]:
     tr = run.device
     if tr is None or run.peak is None or not tr.chips:
         return None
+    a = arch.of(run.model)
     flops = 0.0
     for t, seqs in decode_steps(run.requests).items():
         if tr.t0 <= t <= tr.t1:
-            flops += counts.decode_step(run.model, [c for _, c in seqs])[0]
+            flops += a.decode_step(run.model, [c for _, c in seqs])[0]
     for r in run.requests:
         if r.token_times and tr.t0 <= r.token_times[0] <= tr.t1:
-            flops += counts.prefill(run.model, r.prompt_len)[0]
+            flops += a.prefill(run.model, r.prompt_len)[0]
     if flops == 0:
         return None
     return 100.0 * flops / (tr.window_s * run.chips

@@ -5,12 +5,9 @@ file of ``bench/configs`` (its ``model`` part) and the weights that
 ``bench.weights`` makes from the run's seed, and computes every matmul in
 float32 at ``highest`` precision, with no cache and no batching: each
 sequence is one teacher-forced pass over its prompt and its served tokens.
-
-It covers multi-head and grouped-query attention, SwiGLU and tanh-GELU
-MLPs, non-parametric and affine LayerNorm, RoPE (half-split rotation) and
-causal sliding-window attention.  Departures from the published models,
-which the program shares and the reference therefore follows, are listed
-in each configuration file under ``departures``.
+The forward pass itself is the family's (``hidden`` and ``logits`` of
+``bench/arch/<arch>.py``); this module holds the linear layer they share
+and the gap functions that ``bench.check`` compares.
 
 ``precision="fp8"`` is the control: every linear layer's weights and
 inputs rounded to float8 e4m3 with a scale per output channel and per
@@ -23,11 +20,11 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from bench import arch
 
 HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
-Q_BLOCK = 512
 
 
 def _fp8(x, axis):
@@ -38,7 +35,7 @@ def _fp8(x, axis):
     return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
 
 
-def _linear(x, w, contract: int, fp8: bool):
+def linear(x, w, contract: int, fp8: bool):
     """x [..., in...] @ w [in..., out...] over ``contract`` leading axes of
     w, in float32 (fp8: both sides rounded first)."""
     w = w.astype(jnp.float32)
@@ -48,99 +45,11 @@ def _linear(x, w, contract: int, fp8: bool):
     return jnp.tensordot(x, w, axes=contract, precision=HI)
 
 
-def _norm(m: Dict, p: Dict, x):
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    y = (x - mean) / jnp.sqrt(var + m["norm_eps"])
-    if m["norm"] == "layernorm":
-        y = y * p["scale"] + p["bias"]
-    return y
-
-
-def _rope(x, pos, theta: float):
-    """x [S, H, D], half-split rotation at absolute positions ``pos``."""
-    half = x.shape[-1] // 2
-    inv = 1.0 / (theta ** (np.arange(half, dtype=np.float32) / half))
-    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
-    c, s = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    a, b = x[..., :half], x[..., half:]
-    return jnp.concatenate([a * c - b * s, b * c + a * s], axis=-1)
-
-
-def _attention(m: Dict, q, k, v):
-    """Causal (optionally sliding-window) attention, query block by query
-    block so that the scores fit.  q [S, H, D]; k, v [S, KVH, D]."""
-    S, H, D = q.shape
-    G = H // m["num_kv_heads"]
-    k = jnp.repeat(k, G, axis=1)       # head i reads kv head i // G
-    v = jnp.repeat(v, G, axis=1)
-    window = m.get("sliding_window") or 0
-    kpos = jnp.arange(S)
-
-    def block(i):
-        qb = jax.lax.dynamic_slice_in_dim(q, i * Q_BLOCK, Q_BLOCK, 0)
-        qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-        s = jnp.einsum("qhd,khd->hqk", qb, k, precision=HI) / np.sqrt(D)
-        ok = kpos[None, :] <= qpos[:, None]
-        if window:
-            ok &= kpos[None, :] > qpos[:, None] - window
-        s = jnp.where(ok[None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("hqk,khd->qhd", p, v, precision=HI)
-
-    out = jax.lax.map(block, jnp.arange(S // Q_BLOCK))
-    return out.reshape(S, H, D)
-
-
-def _mlp(m: Dict, p: Dict, x, fp8: bool):
-    h = _linear(x, p["wi"], 1, fp8)
-    if m["activation"] == "swiglu":
-        h = jax.nn.silu(_linear(x, p["wg"], 1, fp8)) * h
-    elif m["activation"] == "gelu_tanh":
-        h = jax.nn.gelu(h, approximate=True)
-    else:
-        raise ValueError(f"activation {m['activation']!r} is not covered")
-    return _linear(h, p["wo"], 1, fp8)
-
-
-def hidden(m: Dict, params: Dict, tokens, fp8: bool = False):
-    """Final normed hidden states [S, d] of one teacher-forced sequence."""
-    S = tokens.shape[0]
-    if m.get("use_bias"):
-        raise ValueError("linear biases are not covered by the reference")
-    if S % Q_BLOCK:
-        raise ValueError(f"sequence length {S} is not a multiple of "
-                         f"{Q_BLOCK}")
-    pos = jnp.arange(S)
-    x = params["embed"][tokens].astype(jnp.float32)
-    blk = params["blocks"][0]
-
-    def layer(x, p):
-        h = _norm(m, p["norm_mixer"], x)
-        a = p["attn"]
-        q = _rope(_linear(h, a["wq"], 1, fp8), pos, m["rope_theta"])
-        k = _rope(_linear(h, a["wk"], 1, fp8), pos, m["rope_theta"])
-        v = _linear(h, a["wv"], 1, fp8)
-        o = _attention(m, q, k, v)
-        x = x + _linear(o, a["wo"], 2, fp8)
-        h = _norm(m, p["norm_ffn"], x)
-        return x + _mlp(m, p["mlp"], h, fp8), None
-
-    x, _ = jax.lax.scan(layer, x, blk)
-    return _norm(m, params["norm_final"], x)
-
-
-def logits(m: Dict, params: Dict, h, fp8: bool = False):
-    """h [..., d] -> float32 logits [..., V]."""
-    if m["tie_embeddings"]:
-        return _linear(h, params["embed"].T, 1, fp8)
-    return _linear(h, params["head"], 1, fp8)
-
-
 def _served_logits(m, params, tokens, start, n_out, fp8):
-    h = hidden(m, params, tokens, fp8)
+    a = arch.of(m)
+    h = a.hidden(m, params, tokens, fp8)
     h = jax.lax.dynamic_slice_in_dim(h, start, n_out, 0)
-    return logits(m, params, h, fp8)
+    return a.logits(m, params, h, fp8)
 
 
 @functools.lru_cache(maxsize=None)

@@ -6,9 +6,12 @@ import re
 import subprocess
 import sys
 
+import jax
 import pytest
 
-from bench import harness, traffic
+from bench import arch, harness, traffic, weights
+from repro.distributed.sharding import unbox
+from repro.models import build_model
 
 BENCH = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 ROOT = os.path.dirname(BENCH)
@@ -31,13 +34,14 @@ def test_configuration_files_load_and_build(path):
             assert cfg["file"] == f"bench/configs/{path}"
             assert data["source"] == cfg["source"]
             assert sorted(cfg["reduced"]) == sorted(data["reduced"])
-    program = harness.program_config(data)
+    # the family's module lays the weights out as the program's model
+    # does, at full width (shapes only: nothing is made)
     m = data["model"]
-    assert (program.num_layers, program.d_model, program.num_heads,
-            program.num_kv_heads, program.head_dim, program.d_ff,
-            program.vocab_size) == (
-        m["num_layers"], m["d_model"], m["num_heads"], m["num_kv_heads"],
-        m["head_dim"], m["d_ff"], m["vocab_size"])
+    assert os.path.isfile(os.path.join(arch.DIR, f"{m['arch']}.py"))
+    a = arch.of(m)
+    model = build_model(a.program_config(data))
+    weights.check_layout(weights.layout(m), jax.eval_shape(
+        lambda: unbox(model.init(jax.random.PRNGKey(0)))))
     assert 0 < data["check"]["logit_gap_limit"]
 
 

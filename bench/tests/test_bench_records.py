@@ -93,6 +93,27 @@ def test_itl_p95_shrugs_off_a_stall_that_moves_the_tpot_p90():
     assert itl[1] == pytest.approx(itl[0])
 
 
+@pytest.mark.parametrize("waited", [0.04, 0.06])
+def test_itl_p50_and_p99_hold_where_the_p95_jumps(waited):
+    """Gaps that waited on a prefill, 4% or 6% of them, put the pooled p95
+    on one side of their edge or the other; the p50 reads the step and the
+    p99 the wait."""
+    from bench import harness
+    rs = []
+    for i in range(20):
+        t, ts = 10.0 + 0.5 * i, []
+        for k in range(50):
+            t += 0.03 if (50 * i + k) % 100 < 100 * waited else 0.011
+            ts.append(t)
+        rs.append(req(10.0 + 0.5 * i, ts))
+    run = types.SimpleNamespace(requests=rs, w0=10.0, w1=30.0)
+    p50 = harness.load_reader("metrics", "itl_p50_s")(run)
+    p95 = harness.load_reader("endtoend", "itl_p95_s")(run)
+    p99 = harness.load_reader("metrics", "itl_p99_s")(run)
+    assert p50 == pytest.approx(0.011) and p99 == pytest.approx(0.03)
+    assert p95 == pytest.approx(0.011 if waited < 0.05 else 0.03)
+
+
 def test_tokens_in_the_window_include_unfinished_requests():
     rs = [req(0.0, [9.0, 10.0, 10.1]), req(0.0, [19.9, 20.0, 20.1])]
     assert records.tokens_in(rs, 10.0, 20.0) == 4
@@ -158,16 +179,16 @@ def test_decode_steps_group_sequences_by_the_engine_stamp():
 
 
 def test_step_mfu_counts_steps_in_the_traced_window():
-    from bench import counts
-    m = {"num_layers": 1, "d_model": 4, "num_heads": 1, "num_kv_heads": 1,
-         "head_dim": 4, "d_ff": 8, "vocab_size": 16, "activation": "gelu",
+    from bench import arch
+    m = {"arch": "dense", "num_layers": 1, "d_model": 4, "num_heads": 1,
+         "num_kv_heads": 1, "head_dim": 4, "d_ff": 8, "vocab_size": 16, "activation": "gelu",
          "norm": "nonparam_ln", "tie_embeddings": True,
          "param_dtype": "bfloat16", "kv_dtype": "bfloat16"}
     a = req(0.0, [1.0, 2.0, 3.0], prompt_len=5)
     dev = types.SimpleNamespace(t0=1.5, t1=2.5, chips=1, window_s=1.0)
     run = types.SimpleNamespace(device=dev, requests=[a], model=m, chips=1,
                                 peak={"flops_per_s": 1e3})
-    want = counts.decode_step(m, [6])[0]
+    want = arch.load("dense").decode_step(m, [6])[0]
     assert records.step_mfu(run) == pytest.approx(100 * want / 1e3)
     run.peak = None
     assert records.step_mfu(run) is None

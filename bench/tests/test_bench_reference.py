@@ -3,15 +3,17 @@
 
 These check the reference and the comparison, not the yardstick's numbers:
 they run at ``.reduced()``-like widths on the CPU."""
+import functools
 import json
 import os
 import types
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import check, harness, reference, weights
+from bench import arch, check, harness, reference, weights
 
 BENCH = os.path.join(os.path.dirname(__file__), "..")
 
@@ -33,7 +35,7 @@ def small(name, window=16):
 
 def program_logits(cfg, params, toks):
     from repro.models import build_model
-    model = build_model(harness.program_config(cfg))
+    model = build_model(arch.of(cfg["model"]).program_config(cfg))
     x, _ = model.forward(params, {"tokens": toks[None]})
     return np.asarray(model._logits(params, x)[0])
 
@@ -45,8 +47,8 @@ def test_reference_agrees_with_the_program(name):
     params = weights.make_params(m, 3)
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 256, 512),
                        jnp.int32)
-    ours = np.asarray(reference.logits(m, params,
-                                       reference.hidden(m, params, toks)))
+    a = arch.of(m)
+    ours = np.asarray(a.logits(m, params, a.hidden(m, params, toks)))
     theirs = program_logits(cfg, params, toks)
     # the program computes in bfloat16: 2^-8 relative per rounding, over
     # two layers; logits here have a standard deviation near 1
@@ -63,10 +65,10 @@ def test_fp8_control_reads_far_above_the_program(name):
     params = weights.make_params(m, 5)
     toks = jnp.asarray(np.random.default_rng(1).integers(0, 256, 512),
                        jnp.int32)
-    lr = np.asarray(reference.logits(m, params,
-                                     reference.hidden(m, params, toks)))
-    lc = np.asarray(reference.logits(
-        m, params, reference.hidden(m, params, toks, fp8=True), fp8=True))
+    a = arch.of(m)
+    lr = np.asarray(a.logits(m, params, a.hidden(m, params, toks)))
+    lc = np.asarray(a.logits(m, params, a.hidden(m, params, toks, fp8=True),
+                             fp8=True))
     lp = program_logits(cfg, params, toks)
 
     def widest(pick):
@@ -84,8 +86,8 @@ def test_gap_functions_read_the_served_positions():
     params = weights.make_params(m, 2)
     prompt = list(np.random.default_rng(2).integers(0, 256, 20))
     toks = jnp.asarray(prompt + [0] * (512 - 20), jnp.int32)
-    lr = np.asarray(reference.logits(m, params,
-                                     reference.hidden(m, params, toks)))
+    a = arch.of(m)
+    lr = np.asarray(a.logits(m, params, a.hidden(m, params, toks)))
     best = int(lr[19].argmax())
     served = np.zeros(8, np.int32)
     served[0] = best
@@ -153,7 +155,9 @@ def altered_token(step):
 
 
 def state_unchanged(step):
-    """A decode step that returns the cache it was given."""
+    """A decode step that returns the cache it was given, jitted with the
+    cache donated as the step is, so that the engine can go on using it."""
+    @functools.partial(jax.jit, static_argnums=0, donate_argnums=3)
     def broken(model, params, toks, cache, lens):
         logits, _ = step(model, params, toks, cache, lens)
         return logits, cache
@@ -200,5 +204,6 @@ def test_control_readings_fail_the_runs_comparison(tmp_path):
 def test_a_broken_timed_path_is_not_correct(tmp_path, monkeypatch, fault):
     res = run_small(tmp_path, monkeypatch, decode=fault)
     assert res["correct"] is False
+    assert res["compared"]["failed_requests"]["value"] == 0
     assert res["compared"]["logit_gap"]["value"] > \
         res["compared"]["logit_gap"]["limit"]

@@ -1,14 +1,11 @@
 """Random weights from ``--seed``, made on the device in one jitted call.
 
 The benchmark makes the weights itself, in the layout the program's model
-takes (``Model.init`` after unboxing: one stacked tree per block, leading
-axis = layers), so that the plain reference can make the same weights
-again from the same seed and share nothing with the program.  The layout
-is checked against the program's own ``jax.eval_shape`` at set-up.
-
-Scales follow the usual fan-in rule (std 1/sqrt(fan_in); embeddings
-1/sqrt(d_model)).  LayerNorm scales and biases are drawn too (around 1
-and 0), so that the comparison covers them.
+takes, so that the plain reference can make the same weights again from
+the same seed and share nothing with the program.  The layout, its scales
+and the tree are the family's (``shapes`` and ``nest`` of
+``bench/arch/<arch>.py``); the layout is checked against the program's
+own ``jax.eval_shape`` at set-up.
 """
 from __future__ import annotations
 
@@ -17,7 +14,8 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from bench import arch
 
 
 def root_key(seed: int):
@@ -27,56 +25,11 @@ def root_key(seed: int):
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def shapes(m: Dict) -> Dict:
-    """Leaf name -> (shape, dtype, std, mean) in the program's layout."""
-    L, d, h, kvh, hd, f, v = (m["num_layers"], m["d_model"], m["num_heads"],
-                              m["num_kv_heads"], m["head_dim"], m["d_ff"],
-                              m["vocab_size"])
-    bf = jnp.dtype(m["param_dtype"])
-    out = {
-        "embed": ((v, d), bf, 1 / np.sqrt(d), 0.0),
-        "attn.wq": ((L, d, h, hd), bf, 1 / np.sqrt(d), 0.0),
-        "attn.wk": ((L, d, kvh, hd), bf, 1 / np.sqrt(d), 0.0),
-        "attn.wv": ((L, d, kvh, hd), bf, 1 / np.sqrt(d), 0.0),
-        "attn.wo": ((L, h, hd, d), bf, 1 / np.sqrt(h * hd), 0.0),
-        "mlp.wi": ((L, d, f), bf, 1 / np.sqrt(d), 0.0),
-        "mlp.wo": ((L, f, d), bf, 1 / np.sqrt(f), 0.0),
-    }
-    if m["activation"] in ("swiglu", "geglu"):
-        out["mlp.wg"] = ((L, d, f), bf, 1 / np.sqrt(d), 0.0)
-    if not m["tie_embeddings"]:
-        out["head"] = ((d, v), bf, 1 / np.sqrt(d), 0.0)
-    if m["norm"] == "layernorm":
-        for n in ("norm_mixer", "norm_ffn"):
-            out[f"{n}.scale"] = ((L, d), jnp.float32, 0.1, 1.0)
-            out[f"{n}.bias"] = ((L, d), jnp.float32, 0.1, 0.0)
-        out["norm_final.scale"] = ((d,), jnp.float32, 0.1, 1.0)
-        out["norm_final.bias"] = ((d,), jnp.float32, 0.1, 0.0)
-    elif m["norm"] == "rmsnorm":
-        raise ValueError("rmsnorm configurations are not laid out here yet")
-    return out
-
-
-def _nest(flat: Dict) -> Dict:
-    """Flat leaf names -> the program's tree."""
-    block = {"attn": {}, "mlp": {}, "norm_mixer": {}, "norm_ffn": {}}
-    tree = {"norm_final": {}}
-    for name, x in flat.items():
-        top, _, leaf = name.partition(".")
-        if top in block:
-            block[top][leaf] = x
-        elif top == "norm_final":
-            tree["norm_final"][leaf] = x
-        else:
-            tree[top] = x
-    tree["blocks"] = [block]
-    return tree
-
-
 @functools.lru_cache(maxsize=None)
 def _maker(spec_items):
     m = dict(spec_items)
-    table = shapes(m)
+    a = arch.of(m)
+    table = a.shapes(m)
 
     @jax.jit
     def make(key):
@@ -86,19 +39,28 @@ def _maker(spec_items):
             k = jax.random.fold_in(key, i)
             x = jax.random.normal(k, shape, dtype) * jnp.asarray(std, dtype)
             flat[name] = x + jnp.asarray(mean, dtype) if mean else x
-        return _nest(flat)
+        return a.nest(flat)
 
     return make
 
 
+def _spec(m: Dict):
+    return tuple(sorted((k, v) for k, v in m.items()
+                        if not isinstance(v, (list, dict))))
+
+
 def make_params(m: Dict, seed: int, device=None):
     """The weights of seed ``seed`` on ``device`` (one jitted call)."""
-    make = _maker(tuple(sorted((k, v) for k, v in m.items()
-                               if not isinstance(v, (list, dict)))))
+    make = _maker(_spec(m))
     key = root_key(seed)
     if device is not None:
         key = jax.device_put(key, device)
     return jax.block_until_ready(make(key))
+
+
+def layout(m: Dict):
+    """The shapes and dtypes of ``make_params``'s tree, with nothing made."""
+    return jax.eval_shape(_maker(_spec(m)), root_key(0))
 
 
 def check_layout(params_shape, program_shape) -> None:
